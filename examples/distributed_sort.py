@@ -1,7 +1,11 @@
-"""Pod-scale fractal sort on 8 (placeholder) devices: local histograms,
+"""Pod-scale fractal sort over every device present: local histograms,
 one tapered psum merge, exact global ranks, one all_to_all — no sampling.
 
     PYTHONPATH=src python examples/distributed_sort.py
+
+On a CPU host it asks for 8 placeholder devices; on an accelerator host
+it uses the chips it finds.  The mesh axis is the largest power of two
+that the devices cover.
 """
 
 import os
@@ -13,10 +17,11 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
-from repro.compat import make_mesh  # noqa: E402
 from repro.core import distributed_fractal_sort  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
-mesh = make_mesh((8,), ("data",))
+D = 1 << (len(jax.devices()).bit_length() - 1)
+mesh = make_mesh((D,), ("data",))
 rng = np.random.default_rng(0)
 
 for name, keys in {
@@ -27,5 +32,5 @@ for name, keys in {
     out, overflow = distributed_fractal_sort(ks, mesh, "data", 16)
     ok = bool((out == jnp.sort(ks)).all())
     print(f"{name:12s}: sorted={ok} overflow={bool(overflow)} "
-          f"(8 shards x {len(keys) // 8} keys)")
+          f"({D} shards x {len(keys) // D} keys)")
 print("distributed sort OK — same code path scales to the 16x16 pod mesh")

@@ -46,7 +46,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.executor import DistributedBackend, PlanExecutor
 from repro.core.fractal_sort import fractal_rank, rank_engine
 from repro.core.sort_plan import make_sort_plan, pick_engine, scatter_tile_len
@@ -190,7 +189,7 @@ def _make_distributed(body_fn, mesh, axis: str, p: int,
         body = functools.partial(
             body_fn, plan=plan, axis=axis, capacity=cap, batch=batch,
             taper_wire=taper_wire)
-        return compat.shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(axis),) * (1 + num_payloads),
             out_specs=(P(axis),) * (1 + payloads_out) + (P(),),
@@ -355,7 +354,7 @@ def make_fragment_placer(mesh, axis: str, num_words: int,
 
     def fn(words, dest, tag):
         assert words.ndim == 2 and words.shape[1] == num_words
-        return compat.shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(axis), P(axis), P(axis)),
             out_specs=(P(axis), P(axis)),

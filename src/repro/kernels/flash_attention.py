@@ -26,11 +26,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import default_interpret
 
 NEG_INF = -1e30
 
@@ -80,7 +83,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                                              "interpret"))
 def flash_attention_kernel(q, k, v, causal: bool = True,
                            block_q: int = 128, block_kv: int = 128,
-                           interpret: bool = True):
+                           interpret: Optional[bool] = None):
     """q: (B, Sq, H, hd); k, v: (B, Skv, H, hd) (kv repeated to H heads).
 
     Returns (B, Sq, H, hd).  Blocks should be multiples of 128 on the
@@ -120,6 +123,6 @@ def flash_attention_kernel(q, k, v, causal: bool = True,
             pltpu.VMEM((cq,), jnp.float32),      # running denom
             pltpu.VMEM((cq, hd), jnp.float32),   # accumulator
         ],
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(qf, kf, vf)
     return out[:, :Sq].reshape(B, H, Sq, hd).transpose(0, 2, 1, 3)

@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import default_interpret
+
 DEFAULT_BLOCK = 1024
 
 
@@ -64,7 +66,7 @@ def _histogram_kernel(keys_ref, init_ref, out_ref, *, n_bins: int,
                                              "taper_in_tile"))
 def fractal_histogram(keys: jnp.ndarray, n_bins: int,
                       block: int = DEFAULT_BLOCK,
-                      interpret: bool = True,
+                      interpret: Optional[bool] = None,
                       taper_in_tile: bool = True,
                       init: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Leaf counts (bincount) of ``keys`` over ``[0, n_bins)``.
@@ -96,12 +98,13 @@ def fractal_histogram(keys: jnp.ndarray, n_bins: int,
         # accumulator block pinned for the whole grid (index_map -> 0).
         out_specs=pl.BlockSpec((n_bins,), lambda i: (0,)),
         out_shape=jax.ShapeDtypeStruct((n_bins,), jnp.int32),
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(keys.astype(jnp.int32), init.astype(jnp.int32))
 
 
 def digit_histograms(keys: jnp.ndarray, passes, block: int = DEFAULT_BLOCK,
-                     interpret: bool = True, taper_in_tile: bool = True,
+                     interpret: Optional[bool] = None,
+                     taper_in_tile: bool = True,
                      init=None):
     """Multi-digit driver: one leaf histogram per :class:`DigitPass`.
 

@@ -13,8 +13,8 @@ across the sequential grid.  The kernel is *gather-free*: every per-key
 lookup is phrased through the one-hot matrix so it maps onto the MXU /
 VPU instead of serialized VMEM gathers:
 
-    base  = onehot @ (bin_start + carry)          # (block,)
-    intra = rowsum(strict_running_onehot * onehot)
+    base  = rowsum(onehot * (bin_start + carry))  # (block,), int32 VPU
+    intra = rowsum((strict_lower_tri @ onehot) * onehot)   # MXU
     rank  = base + intra
 
 One read of the key stream, one write of the rank stream; the carry never
@@ -28,10 +28,10 @@ while the tile feeds the MXU, ruinous for wide digits.
 block segment boundaries off the sorted composites with ``searchsorted``
 probes, and emits ranks with one in-block scatter — O(block log block +
 n_bins) per step, digit-width independent.  The same VMEM carry scratch
-streams across the grid.  Off-TPU (interpret mode, this repo's CI) the
-sort and probes execute as ordinary XLA ops; on a real TPU the in-kernel
-sort is the port's open risk, and the MXU-shaped one-hot engine stays the
-default there (see ``autotune_plan``'s per-backend cache).
+streams across the grid.  It runs in interpret mode only: the in-kernel
+sort, ``searchsorted``, gather and scatter have no Pallas TPU lowering,
+so asking for it compiled raises instead of falling back to the one-hot
+kernel.
 """
 
 from __future__ import annotations
@@ -42,6 +42,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import default_interpret
 
 DEFAULT_BLOCK = 1024
 
@@ -56,18 +58,28 @@ def _rank_kernel(keys_ref, bin_start_ref, rank_ref, carry_ref, *,
 
     keys = keys_ref[...]  # (block,)
     cols = jax.lax.broadcasted_iota(jnp.int32, (block, n_bins), 1)
-    onehot = (keys[:, None] == cols).astype(jnp.int32)
-    running = jnp.cumsum(onehot, axis=0) - onehot  # strictly-before count
-    intra = (running * onehot).sum(axis=1)
-    base = onehot @ (bin_start_ref[...] + carry_ref[...])
+    hit = keys[:, None] == cols
+    onehot = hit.astype(jnp.bfloat16)
+    # strictly-earlier equal keys: strict-lower-triangular @ one-hot on the
+    # MXU.  0/1 operands are exact in bf16 and the f32 accumulator is
+    # exact for counts below 2**24 (a tile holds `block` keys).
+    row = jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
+    tri = (col < row).astype(jnp.bfloat16)
+    running = jnp.dot(tri, onehot, preferred_element_type=jnp.float32)
+    intra = jnp.sum(jnp.where(hit, running, 0.0), axis=1).astype(jnp.int32)
+    # the bin's start is picked by a masked lane sum, kept in int32:
+    # starts reach n, past f32's exact range.
+    start = bin_start_ref[...] + carry_ref[...]
+    base = jnp.sum(jnp.where(hit, start[None, :], 0), axis=1)
     rank_ref[...] = base + intra
-    carry_ref[...] += onehot.sum(axis=0)
+    carry_ref[...] += jnp.sum(hit.astype(jnp.int32), axis=0)
 
 
 @functools.partial(jax.jit, static_argnames=("n_bins", "block", "interpret"))
 def fractal_rank_kernel(keys: jnp.ndarray, bin_start: jnp.ndarray,
                         n_bins: int, block: int = DEFAULT_BLOCK,
-                        interpret: bool = True) -> jnp.ndarray:
+                        interpret: Optional[bool] = None) -> jnp.ndarray:
     """Stable output slot per key given precomputed exclusive bin starts.
 
     ``keys``: 1-D int32 in [0, n_bins) (pad with -1: padded ranks emit
@@ -88,7 +100,7 @@ def fractal_rank_kernel(keys: jnp.ndarray, bin_start: jnp.ndarray,
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((keys.shape[0],), jnp.int32),
         scratch_shapes=[pltpu_scratch((n_bins,), jnp.int32)],
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(keys.astype(jnp.int32), bin_start.astype(jnp.int32))
     return out[:n]
 
@@ -98,6 +110,14 @@ def pltpu_scratch(shape, dtype):
     from jax.experimental.pallas import tpu as pltpu
 
     return pltpu.VMEM(shape, dtype)
+
+
+def _require_interpret(interpret: Optional[bool]) -> None:
+    if not default_interpret(interpret):
+        raise NotImplementedError(
+            "the scatter rank kernel has no Pallas TPU lowering (in-kernel "
+            "sort, searchsorted, gather and scatter); run it with "
+            "interpret=True or use the one-hot engine")
 
 
 def _rank_scatter_kernel(keys_ref, bin_start_ref, rank_ref, carry_ref, *,
@@ -131,14 +151,17 @@ def _rank_scatter_kernel(keys_ref, bin_start_ref, rank_ref, carry_ref, *,
 @functools.partial(jax.jit, static_argnames=("n_bins", "block", "interpret"))
 def fractal_rank_scatter_kernel(keys: jnp.ndarray, bin_start: jnp.ndarray,
                                 n_bins: int, block: int = DEFAULT_BLOCK,
-                                interpret: bool = True) -> jnp.ndarray:
+                                interpret: Optional[bool] = None
+                                ) -> jnp.ndarray:
     """Scatter-engine ranks given precomputed exclusive bin starts.
 
     ``keys``: 1-D int32 in [0, n_bins) (the driver pads with ``n_bins``,
     which sorts past every real composite; padded slots emit garbage
     ranks and are sliced).  Same signature and output as
     :func:`fractal_rank_kernel`, digit-width-independent arithmetic.
+    Interpret mode only: compiled, it raises ``NotImplementedError``.
     """
+    _require_interpret(interpret)
     assert block & (block - 1) == 0, f"block={block} must be a power of two"
     assert n_bins << (block.bit_length() - 1) < (1 << 32), (
         f"composite packing overflow: n_bins={n_bins} block={block}")
@@ -157,13 +180,14 @@ def fractal_rank_scatter_kernel(keys: jnp.ndarray, bin_start: jnp.ndarray,
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((keys.shape[0],), jnp.int32),
         scratch_shapes=[pltpu_scratch((n_bins,), jnp.int32)],
-        interpret=interpret,
+        interpret=True,
     )(keys.astype(jnp.int32), bin_start.astype(jnp.int32))
     return out[:n]
 
 
 def fractal_rank_counts(digit: jnp.ndarray, n_bins: int,
-                        block: int = DEFAULT_BLOCK, interpret: bool = True,
+                        block: int = DEFAULT_BLOCK,
+                        interpret: Optional[bool] = None,
                         bin_start: jnp.ndarray = None,
                         engine: Optional[str] = None):
     """Kernel-path rank primitive on an already-extracted digit stream:
@@ -180,12 +204,15 @@ def fractal_rank_counts(digit: jnp.ndarray, n_bins: int,
     (distributed merge).  ``engine`` is the plan's per-pass hint; ``None``
     keeps the one-hot kernel — the MXU-shaped tile is the TPU-native
     default, so the kernel driver does *not* apply the CPU cost model.
+    ``"scatter"`` runs in interpret mode only and raises compiled.
     """
     from repro.core.fractal_tree import exclusive_cumsum
     from repro.kernels.fractal_histogram import fractal_histogram
 
     assert engine in (None, "onehot", "scatter"), (
         f"unknown kernel rank engine {engine!r}")
+    if engine == "scatter":
+        _require_interpret(interpret)
     counts = fractal_histogram(digit, n_bins, block=block,
                                interpret=interpret)
     if bin_start is None:
@@ -198,7 +225,8 @@ def fractal_rank_counts(digit: jnp.ndarray, n_bins: int,
 
 
 def fractal_rank_digit(keys: jnp.ndarray, digit_pass,
-                       block: int = DEFAULT_BLOCK, interpret: bool = True,
+                       block: int = DEFAULT_BLOCK,
+                       interpret: Optional[bool] = None,
                        bin_start: jnp.ndarray = None):
     """Multi-digit driver: stable ranks on one :class:`DigitPass` digit.
 

@@ -15,10 +15,13 @@ keys; the CDF block stays pinned in VMEM for the whole grid.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import default_interpret
 
 DEFAULT_BLOCK = 1024
 
@@ -38,7 +41,7 @@ def _reconstruct_kernel(cdf_ref, trailing_ref, out_ref, *, n_bins: int,
 def fractal_reconstruct(counts: jnp.ndarray, trailing: jnp.ndarray,
                         n_bins: int, t_bits: int,
                         block: int = DEFAULT_BLOCK,
-                        interpret: bool = True) -> jnp.ndarray:
+                        interpret: Optional[bool] = None) -> jnp.ndarray:
     """Sorted keys from bin ``counts`` and sorted-order ``trailing`` entries.
 
     ``counts``: (n_bins,) int32; ``trailing``: (n,) int32 (only low
@@ -60,14 +63,15 @@ def fractal_reconstruct(counts: jnp.ndarray, trailing: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((trailing.shape[0],), jnp.int32),
-        interpret=interpret,
+        interpret=default_interpret(interpret),
     )(cdf, trailing.astype(jnp.int32))
     return out[:n]
 
 
 def fractal_reconstruct_plan(counts: jnp.ndarray, trailing: jnp.ndarray,
                              plan, block: int = DEFAULT_BLOCK,
-                             interpret: bool = True) -> jnp.ndarray:
+                             interpret: Optional[bool] = None
+                             ) -> jnp.ndarray:
     """Multi-digit driver: Algorithm 5 for a :class:`SortPlan`'s MSD pass.
 
     The plan's final pass defines both the bin space (``2**depth``) and the

@@ -16,6 +16,7 @@ bandwidth-minimal fractal pipeline.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +27,7 @@ from repro.kernels.fractal_rank import fractal_rank_kernel
 
 @functools.partial(jax.jit, static_argnames=("num_experts", "block", "interpret"))
 def moe_dispatch(expert_ids: jnp.ndarray, num_experts: int,
-                 block: int = 1024, interpret: bool = True):
+                 block: int = 1024, interpret: Optional[bool] = None):
     """Dispatch metadata for flattened top-k expert assignments.
 
     Args:

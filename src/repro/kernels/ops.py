@@ -1,25 +1,21 @@
-"""Public jit'd wrappers over the Pallas kernels.
+"""Public names for the Pallas kernels, plus the kernel-path sorts.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernels are written for the TPU target and validated by executing the
-kernel bodies in interpret mode against the ``ref.py`` oracles).  On a real
-TPU backend the flag flips to compiled automatically.
+Every kernel resolves ``interpret=None`` through :func:`default_interpret`:
+compiled on a TPU backend, interpreted elsewhere (the CPU test suite
+checks the kernel bodies in interpret mode against the ``ref.py``
+oracles).
 """
 
 from __future__ import annotations
 
-import functools
-
-import jax
-
-from repro.kernels import ref
-from repro.kernels.fractal_histogram import digit_histograms as _digit_hists
-from repro.kernels.fractal_histogram import fractal_histogram as _hist
-from repro.kernels.fractal_rank import fractal_rank_digit as _rank_digit
-from repro.kernels.fractal_rank import fractal_rank_kernel as _rank
-from repro.kernels.fractal_reconstruct import fractal_reconstruct as _recon
-from repro.kernels.flash_attention import flash_attention_kernel as _flash
-from repro.kernels.moe_dispatch import moe_dispatch as _dispatch
+from repro.kernels import default_interpret
+from repro.kernels.flash_attention import flash_attention_kernel as flash_attention
+from repro.kernels.fractal_histogram import digit_histograms
+from repro.kernels.fractal_histogram import fractal_histogram as histogram
+from repro.kernels.fractal_rank import fractal_rank_digit as rank_digit
+from repro.kernels.fractal_rank import fractal_rank_kernel as rank
+from repro.kernels.fractal_reconstruct import fractal_reconstruct as reconstruct
+from repro.kernels.moe_dispatch import moe_dispatch
 
 __all__ = [
     "default_interpret",
@@ -35,60 +31,22 @@ __all__ = [
 ]
 
 
-@functools.cache
-def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _onehot_executor(n: int, p: int, block: int, interpret, max_bins_log2):
+    """A one-hot plan (the rank engine that compiles for the TPU) and a
+    :class:`~repro.core.executor.PlanExecutor` over the Pallas backend."""
+    from repro.core.executor import PallasBackend, PlanExecutor
+    from repro.core.sort_plan import make_sort_plan
 
-
-def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
-                    block_kv: int = 128, interpret=None):
-    interpret = default_interpret() if interpret is None else interpret
-    return _flash(q, k, v, causal=causal, block_q=block_q,
-                  block_kv=block_kv, interpret=interpret)
-
-
-def histogram(keys, n_bins: int, block: int = 1024, interpret=None):
-    interpret = default_interpret() if interpret is None else interpret
-    return _hist(keys, n_bins, block=block, interpret=interpret)
-
-
-def digit_histograms(keys, passes, block: int = 1024, interpret=None):
-    interpret = default_interpret() if interpret is None else interpret
-    return _digit_hists(keys, passes, block=block, interpret=interpret)
-
-
-def rank_digit(keys, digit_pass, block: int = 1024, interpret=None,
-               bin_start=None):
-    interpret = default_interpret() if interpret is None else interpret
-    return _rank_digit(keys, digit_pass, block=block, interpret=interpret,
-                       bin_start=bin_start)
-
-
-def rank(keys, bin_start, n_bins: int, block: int = 1024, interpret=None):
-    interpret = default_interpret() if interpret is None else interpret
-    return _rank(keys, bin_start, n_bins, block=block, interpret=interpret)
-
-
-def reconstruct(counts, trailing, n_bins: int, t_bits: int,
-                block: int = 1024, interpret=None):
-    interpret = default_interpret() if interpret is None else interpret
-    return _recon(counts, trailing, n_bins, t_bits, block=block,
-                  interpret=interpret)
-
-
-def moe_dispatch(expert_ids, num_experts: int, block: int = 1024,
-                 interpret=None):
-    interpret = default_interpret() if interpret is None else interpret
-    return _dispatch(expert_ids, num_experts, block=block,
-                     interpret=interpret)
+    plan = make_sort_plan(n, p, max_bins_log2=max_bins_log2, engine="onehot")
+    return plan, PlanExecutor(PallasBackend(block=block, interpret=interpret))
 
 
 def fractal_sort_kernel(keys, p: int, block: int = 1024, interpret=None,
                         max_bins_log2=None):
     """End-to-end kernel-path sort for keys in [0, 2**p), p <= 32.
 
-    Thin wrapper: builds a :class:`~repro.core.sort_plan.SortPlan` and
-    hands it to a :class:`~repro.core.executor.PlanExecutor` over the
+    Thin wrapper: builds a one-hot :class:`~repro.core.sort_plan.SortPlan`
+    and hands it to a :class:`~repro.core.executor.PlanExecutor` over the
     :class:`~repro.core.executor.PallasBackend` — per LSD pass, histogram
     kernel → exclusive scan → rank kernel → full-key scatter; the final
     MSD pass scatters only the trailing-bit entries and rebuilds prefix
@@ -96,14 +54,9 @@ def fractal_sort_kernel(keys, p: int, block: int = 1024, interpret=None,
     paper calls FractalSortCPU(A), with the pass decomposition bounding
     every kernel's one-hot tile.
     """
-    interpret = default_interpret() if interpret is None else interpret
-
-    from repro.core.executor import PallasBackend, PlanExecutor
-    from repro.core.sort_plan import make_sort_plan
-
-    plan = make_sort_plan(keys.shape[0], p, max_bins_log2=max_bins_log2)
-    backend = PallasBackend(block=block, interpret=interpret)
-    return PlanExecutor(backend).run(keys, plan).astype(keys.dtype)
+    plan, ex = _onehot_executor(keys.shape[0], p, block, interpret,
+                                max_bins_log2)
+    return ex.run(keys, plan).astype(keys.dtype)
 
 
 def fractal_sort_pairs_kernel(keys, values, p: int, block: int = 1024,
@@ -113,12 +66,7 @@ def fractal_sort_pairs_kernel(keys, values, p: int, block: int = 1024,
     for the prefix bits), mirroring
     :func:`repro.core.fractal_sort.fractal_sort_pairs` on the
     :class:`~repro.core.executor.PallasBackend`."""
-    interpret = default_interpret() if interpret is None else interpret
-
-    from repro.core.executor import PallasBackend, PlanExecutor
-    from repro.core.sort_plan import make_sort_plan
-
-    plan = make_sort_plan(keys.shape[0], p, max_bins_log2=max_bins_log2)
-    backend = PallasBackend(block=block, interpret=interpret)
-    out, vals = PlanExecutor(backend).run_pairs(keys, values, plan)
+    plan, ex = _onehot_executor(keys.shape[0], p, block, interpret,
+                                max_bins_log2)
+    out, vals = ex.run_pairs(keys, values, plan)
     return out.astype(keys.dtype), vals

@@ -1,6 +1,6 @@
-"""Production mesh definitions.
+"""Mesh construction.
 
-A *function*, not a module-level constant — importing this module never
+*Functions*, not module-level constants — importing this module never
 touches jax device state (the dry-run forces 512 host devices before any
 jax initialization; tests and benches must keep seeing 1 device).
 """
@@ -9,7 +9,13 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import make_mesh
+
+def make_mesh(axis_shapes, axis_names, **kwargs):
+    """``jax.make_mesh`` with every axis ``Auto``, the sharding mode this
+    codebase is written for (jax's own default is ``Explicit``)."""
+    kwargs.setdefault("axis_types",
+                      (jax.sharding.AxisType.Auto,) * len(axis_names))
+    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -22,7 +22,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.kernels import ops
 from repro.models.layers import dense_init
@@ -178,7 +177,7 @@ def moe_apply(p, cfg: ModelConfig, x, *, interpret: Optional[bool] = None):
         body = functools.partial(
             _moe_ffn_local, cfg=cfg, k=k, C=C, interpret=interpret,
             fsdp_axes="data", dp_axes=tuple(axes), tp_axis="model")
-        out, counts, probs_sum = compat.shard_map(
+        out, counts, probs_sum = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(axes), P("data", None),
                       w_spec["wi"], w_spec["wg"], w_spec["wd"]),

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 
 
 def gpipe_apply(stage_fn: Callable, mesh, axis: str, stage_params, x_micro):
@@ -68,6 +67,6 @@ def gpipe_apply(stage_fn: Callable, mesh, axis: str, stage_params, x_micro):
         return jax.lax.psum(outs, axis)
 
     in_specs = (jax.tree.map(lambda _: P(axis), stage_params), P())
-    return compat.shard_map(body, mesh=mesh, in_specs=in_specs,
-                            out_specs=P(), check_vma=False)(stage_params,
-                                                            x_micro)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=P(), check_vma=False)(stage_params,
+                                                         x_micro)

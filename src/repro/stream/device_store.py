@@ -109,11 +109,11 @@ class DeviceShardStore(PlacementStore):
                  max_bins_log2: int = 16):
         import jax
 
-        from repro import compat
+        from repro.launch.mesh import make_mesh
 
         if mesh is None:
             n_dev = len(jax.devices())
-            mesh = compat.make_mesh((n_dev,), (axis,))
+            mesh = make_mesh((n_dev,), (axis,))
         self.mesh = mesh
         self.axis = axis
         self.batch = batch

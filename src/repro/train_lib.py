@@ -9,7 +9,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro import optim as O
 from repro import sharding as SH
 from repro.configs.base import ModelConfig
@@ -135,7 +134,7 @@ def make_compressed_ddp_step(cfg: ModelConfig, oc: O.OptimizerConfig, mesh,
         return params, opt_state, err_new, metrics
 
     rep = P()
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(rep, rep, P(axis), P(axis)),
         out_specs=(rep, rep, P(axis), rep),

@@ -17,7 +17,8 @@ def _run(body: str):
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         mesh8 = make_mesh((8,), ("data",))
         mesh24 = make_mesh((2, 4), ("data", "model"))
     """) + textwrap.dedent(body)

@@ -187,7 +187,7 @@ def test_distributed_backend_agrees_on_single_device_mesh(rng):
     test_distributed.py subprocesses)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.core import distributed_fractal_sort
 
     mesh = make_mesh((1,), ("data",))
@@ -273,7 +273,7 @@ def test_argsort_duplicate_stability_across_backends(rng, dist, backend):
     else:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.core import distributed_fractal_argsort
 
         mesh = make_mesh((1,), ("data",))
@@ -348,8 +348,7 @@ def test_distributed_overflow_resets_between_runs(rng):
     must not."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro import compat
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.core import DistributedBackend
 
     mesh = make_mesh((1,), ("data",))
@@ -369,7 +368,7 @@ def test_distributed_overflow_resets_between_runs(rng):
                        NamedSharding(mesh, P("data")))
     b = jax.device_put(jnp.asarray(rng.integers(0, 256, n2), jnp.int32),
                        NamedSharding(mesh, P("data")))
-    out1, ov1, out2, ov2 = compat.shard_map(
+    out1, ov1, out2, ov2 = jax.shard_map(
         body, mesh=mesh, in_specs=(P("data"), P("data")),
         out_specs=(P("data"), P(), P("data"), P()))(a, b)
     # on one device every key targets bucket 0: run 1 overflows (64 > 32,

@@ -13,7 +13,7 @@ def test_gpipe_matches_sequential():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import numpy as np, jax, jax.numpy as jnp
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.pipeline import gpipe_apply
 
         mesh = make_mesh((4,), ("stage",))

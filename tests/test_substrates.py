@@ -94,7 +94,7 @@ def test_checkpoint_elastic_reshard(tmp_path):
     """Restore with explicit shardings (elastic restart onto a new mesh)."""
     t = _tree()
     CK.save(str(tmp_path), 3, t)
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((1,), ("data",))
     from jax.sharding import NamedSharding, PartitionSpec as P
 
