@@ -19,6 +19,13 @@ instrumented hot paths pay a dict lookup and nothing else (asserted by
 (tests), :func:`suspended` turns it off for a scope (benchmark timing
 loops must not pay per-span bookkeeping or fill the buffer).
 
+While the collector is on, every open span also holds a
+``jax.profiler.TraceAnnotation`` of its name, so a profiler session
+(``jax.profiler.start_trace``) records the program's spans on its host
+plane, on the same clock as the device's ops.  ``jax.profiler`` is
+imported when the collector first starts (at import only under
+``REPRO_TRACE``).
+
 Finished spans become a :class:`Trace`: exportable as Chrome/Perfetto
 trace-event JSON (:meth:`Trace.export` — load in ``ui.perfetto.dev``)
 and as a machine-readable aggregate tree (:meth:`Trace.summary`) that
@@ -89,6 +96,9 @@ class _Collector:
 
 _collector: Optional[_Collector] = None
 _tls = threading.local()
+# jax.profiler.TraceAnnotation, bound by start(): each open span holds
+# one of its name, putting the span on the profiler's host plane
+_annotation = None
 
 
 def _stack() -> list:
@@ -106,7 +116,8 @@ def enabled() -> bool:
 class Span:
     """A live span: ``with``-entered, attributes settable while open."""
 
-    __slots__ = ("name", "attrs", "sid", "parent_sid", "t0", "_collector")
+    __slots__ = ("name", "attrs", "sid", "parent_sid", "t0", "_collector",
+                 "_profiled")
 
     def __init__(self, collector: _Collector, name: str,
                  attrs: Dict[str, Any]):
@@ -116,6 +127,7 @@ class Span:
         self.sid = collector.open()
         self.parent_sid: Optional[int] = None
         self.t0 = 0.0
+        self._profiled = None
 
     def set(self, **attrs: Any) -> "Span":
         """Merge attributes into the span (overwrites same-named keys)."""
@@ -133,11 +145,17 @@ class Span:
         if stack:
             self.parent_sid = stack[-1].sid
         stack.append(self)
+        if _annotation is not None:
+            self._profiled = _annotation(self.name)
+            self._profiled.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter()
+        if self._profiled is not None:
+            self._profiled.__exit__(None, None, None)
+            self._profiled = None
         stack = _stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -218,7 +236,11 @@ def wrap_ctx(fn):
 def start() -> None:
     """Install the global collector (idempotent).  Called automatically
     at import when ``REPRO_TRACE`` is set truthy."""
-    global _collector
+    global _collector, _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
     if _collector is None:
         _collector = _Collector()
 

@@ -38,9 +38,7 @@ back through these same in-memory primitives.
 
 from __future__ import annotations
 
-import contextlib
 import functools
-import time
 from typing import Mapping, Optional, Tuple
 
 import jax
@@ -69,6 +67,20 @@ __all__ = [
     "sort_rowids_fused",
     "sort_rowids_batched",
 ]
+
+
+def _fetch(x) -> np.ndarray:
+    """``x`` on the host.  A device array's transfer is one
+    ``query.fetch`` span and adds its ``nbytes`` to the
+    ``query.d2h_bytes`` counter; a host array passes through."""
+    if not isinstance(x, jax.Array):
+        return np.asarray(x)
+    nbytes = int(x.nbytes)
+    with trace.span("query.fetch", bytes=nbytes):
+        host = np.asarray(x)
+    metrics.counter("query.d2h_bytes").inc(nbytes)
+    return host
+
 
 def _stream_ops(table):
     """The streaming-operator module when ``table`` is a StreamTable,
@@ -329,14 +341,16 @@ def sort_rowids_fused(codec: CompositeCodec, prepped,
                 jnp.zeros((0,), jnp.int32))
     active = active_words(codec.bits)
     if plans is None:
-        masks = np.asarray(_mask_probe(codec)(prepped))
+        with trace.span("query.probe"):
+            masks = _fetch(_mask_probe(codec)(prepped))
         active = tuple(
             (j, min(eff, int(masks[j]).bit_length()))
             for j, eff in active if int(masks[j]))
     plans = _resolve_plans(n, active, plans)
     pairs_path = (len(widths) == 1 and len(active) == 1
                   and active[0][1] == widths[0])
-    return _fused_chain(codec, active, plans, pairs_path)(prepped)
+    with trace.span("query.chain"):
+        return _fused_chain(codec, active, plans, pairs_path)(prepped)
 
 
 @functools.lru_cache(maxsize=256)
@@ -390,18 +404,11 @@ def sort_rowids_batched(words: jnp.ndarray, bits: int, seg_len_log2: int,
     return _segmented_chain(active, plans, int(seg_len_log2))(words)
 
 
-@contextlib.contextmanager
 def _op_scope(name: str, rows: int):
-    """Per-operator request scope: a ``query.<name>`` span (when tracing)
-    plus the p50/p99-capable latency histogram and request counter the
-    serving layer reads — every in-memory operator call is one
-    "request" in the registry."""
-    t0 = time.perf_counter()
-    with trace.span(f"query.{name}", rows=rows):
-        yield
-    metrics.histogram(f"query.{name}.latency_s").observe(
-        time.perf_counter() - t0)
-    metrics.counter(f"query.{name}.requests").inc()
+    """The ``query.<name>`` span around one in-memory operator call (the
+    shared null handle when tracing is off); the operator's phase spans
+    nest under it."""
+    return trace.span(f"query.{name}", rows=rows)
 
 
 def order_by(table: Table, by, codecs: Optional[Mapping[str, Codec]] = None,
@@ -500,7 +507,10 @@ def _top_k_mem(table: Table, by, k: int, codecs, plans) -> Table:
         # one jitted dispatch: fused encode → leading-digit histogram
         counts, prefix = _prune_hist(codec, top_bits, shift)(prepped)
         cut = jnp.searchsorted(jnp.cumsum(counts), k, side="left")
-        rows = jnp.nonzero(prefix <= cut)[0].astype(jnp.int32)  # host sync
+        keep = prefix <= cut
+        # the host sync: the candidate count sizes the nonzero
+        size = int(_fetch(jnp.sum(keep, dtype=jnp.int32)))
+        rows = jnp.nonzero(keep, size=size)[0].astype(jnp.int32)
         if rows.shape[0] < n:
             # the candidate subset re-resolves its own (tuned) plans:
             # caller-pinned plans were sized for n rows, not ~k
@@ -543,9 +553,9 @@ def _words_searchsorted(sorted_words: np.ndarray, queries: np.ndarray,
     return sorted_rows_upto[rank[m:]]
 
 
-def _segments(sorted_words: jnp.ndarray) -> np.ndarray:
-    """Start index of every run of equal codes in a sorted word matrix."""
-    w = np.asarray(sorted_words)
+def _segments(w: np.ndarray) -> np.ndarray:
+    """Start index of every run of equal codes in a sorted host word
+    matrix."""
     if w.shape[0] == 0:
         return np.zeros((0,), np.int64)
     change = np.any(w[1:] != w[:-1], axis=1)
@@ -565,8 +575,8 @@ def distinct(table: Table, by=None,
     with _op_scope("distinct", len(table)):
         codec, prepped = _key_data(table, by, codecs)
         sorted_words, rowids = sort_rowids_fused(codec, prepped, plans)
-        starts = _segments(sorted_words)
-        return table.take(jnp.asarray(np.asarray(rowids)[starts]))
+        starts = _segments(_fetch(sorted_words))
+        return table.take(jnp.asarray(_fetch(rowids)[starts]))
 
 
 # aggregation spec: out_name -> (column | None, "sum"|"count"|"min"|"max")
@@ -604,15 +614,20 @@ def group_by(table: Table, by, aggs: Mapping[str, Tuple[Optional[str], str]],
 
 
 def _group_by_mem(table: Table, by, aggs, codecs, plans) -> Table:
-    codec, prepped = _key_data(table, by, codecs)
+    with trace.span("query.prepare"):
+        codec, prepped = _key_data(table, by, codecs)
     sorted_words, rowids = sort_rowids_fused(codec, prepped, plans)
-    starts = _segments(sorted_words)
-    rid = np.asarray(rowids)
+    # the first fetch also waits for the chain to finish on the device
+    words = _fetch(sorted_words)
+    rid = _fetch(rowids)
+    with trace.span("query.segments"):
+        starts = _segments(words)
     n = rid.shape[0]
     cols = {}
-    key_cols = codec.decode(jnp.asarray(np.asarray(sorted_words)[starts])) \
-        if len(starts) else tuple(
-            table.column(name)[:0] for name, _ in by)
+    with trace.span("query.decode"):
+        key_cols = codec.decode(jnp.asarray(words[starts])) \
+            if len(starts) else tuple(
+                table.column(name)[:0] for name, _ in by)
     for (name, _), vals in zip(by, key_cols):
         cols[name] = vals
     counts = np.diff(starts, append=n)
@@ -621,11 +636,14 @@ def _group_by_mem(table: Table, by, aggs, codecs, plans) -> Table:
         if op == "count":
             cols[out_name] = jnp.asarray(counts.astype(np.int32))
             continue
-        vals = np.asarray(table.column(col))[rid]
+        column = _fetch(table.column(col))
+        with trace.span("query.gather", column=col):
+            vals = column[rid]
         if len(starts) == 0:
             cols[out_name] = jnp.asarray(vals[:0])
             continue
-        agg = _AGG_UFUNC[op].reduceat(vals, starts)
+        with trace.span("query.reduce", column=col):
+            agg = _AGG_UFUNC[op].reduceat(vals, starts)
         cols[out_name] = agg if vals.dtype == np.float64 else jnp.asarray(agg)
     return Table(cols)
 
@@ -673,7 +691,7 @@ def _join_mem(left: Table, right: Table, on, by, codecs, suffixes,
         "via codecs=")
     lc, lrid = sort_rowids_fused(codec_l, pre_l, plans)
     rc, rrid = sort_rowids_fused(codec_r, pre_r, plans)
-    lc, rc = np.asarray(lc), np.asarray(rc)
+    lc, rc = _fetch(lc), _fetch(rc)
     lo = _words_searchsorted(rc, lc, side="left")
     hi = _words_searchsorted(rc, lc, side="right")
     cnt = hi - lo
@@ -681,8 +699,8 @@ def _join_mem(left: Table, right: Table, on, by, codecs, suffixes,
     lpos = np.repeat(np.arange(cnt.shape[0]), cnt)
     seg_start = np.repeat(np.cumsum(cnt) - cnt, cnt)
     rpos = np.asarray(lo)[lpos] + (np.arange(total) - seg_start)
-    lrows = jnp.asarray(np.asarray(lrid)[lpos])
-    rrows = jnp.asarray(np.asarray(rrid)[rpos])
+    lrows = jnp.asarray(_fetch(lrid)[lpos])
+    rrows = jnp.asarray(_fetch(rrid)[rpos])
     ltab, rtab = left.take(lrows), right.take(rrows)
     keys = {name for name, _ in by}
     out = {name: ltab.column(name) for name, _ in by}

@@ -405,3 +405,35 @@ def test_dispatch_record_feeds_registry():
         before.get("dispatch.test.obs.tag", 0) == 2
     assert after.get("dispatch.test.obs.tag.compiles", 0) - \
         before.get("dispatch.test.obs.tag.compiles", 0) == 2
+
+
+def test_span_holds_profiler_annotation_of_its_name(monkeypatch):
+    """On, every span holds a ``jax.profiler.TraceAnnotation`` of its
+    name (the attributes stay in the record); off, no annotation."""
+    seen = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+            return self
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+            return False
+
+    with obs.tracing() as session:
+        assert trace._annotation is jax.profiler.TraceAnnotation
+        monkeypatch.setattr(trace, "_annotation", Annotation)
+        with trace.span("outer", bytes=3):
+            with trace.span("inner"):
+                pass
+        with trace.suspended():
+            assert trace.span("off", bytes=1) is trace.NULL
+            with trace.span("off"):
+                pass
+    assert seen == [("enter", "outer"), ("enter", "inner"),
+                    ("exit", "inner"), ("exit", "outer")]
+    assert session.trace.find("outer")[0]["attrs"] == {"bytes": 3}

@@ -583,3 +583,61 @@ def test_operators_accept_plans_kwarg(rng):
                     plans=plans).num_rows == distinct(t, "k").num_rows
     tk = top_k(t, "k", 17, plans=plans).to_numpy()
     assert np.array_equal(tk["k"], want["k"][:17])
+
+
+# --- device-to-host accounting -----------------------------------------------
+
+
+def _moved_and_traced(fn):
+    """``fn()``'s ``query.d2h_bytes`` delta and its trace."""
+    from repro import obs
+    from repro.obs import metrics
+
+    with obs.tracing() as session:
+        before = metrics.snapshot()
+        fn()
+        moved = metrics.snapshot_delta(before).get("query.d2h_bytes", 0)
+    return moved, session.trace
+
+
+@pytest.mark.parametrize("values_on", ["host", "device"])
+def test_group_by_counts_each_transfer_once(rng, values_on):
+    n = 1000
+    vals = rng.standard_normal(n)
+    t = Table({"k": jnp.asarray(rng.integers(0, 50, n).astype(np.int32)),
+               "v": vals if values_on == "host"
+               else jnp.asarray(vals.astype(np.float32))})
+    moved, tr = _moved_and_traced(lambda: group_by(
+        t, "k", {"s": ("v", "sum"), "c": (None, "count")}))
+    # sorted uint32 words (one word) + int32 row ids + the (1,) probe mask,
+    # and a device value column once
+    assert moved == 4 * n + 4 * n + 4 + (4 * n if values_on == "device"
+                                         else 0)
+    tr.assert_well_formed()
+    assert tr.total("query.fetch", "bytes") == moved
+    phases = tr.summary()["query.group_by"]["children"]
+    assert {"query.prepare", "query.probe", "query.chain", "query.fetch",
+            "query.segments", "query.decode", "query.gather",
+            "query.reduce"} <= set(phases)
+    assert phases["query.probe"]["children"]["query.fetch"]["count"] == 1
+    assert phases["query.gather"]["count"] == 1
+    assert phases["query.gather"]["children"] == {}
+
+
+@pytest.mark.parametrize("op", ["order_by", "distinct", "top_k",
+                                "sort_merge_join"])
+def test_operators_count_their_fetches(rng, op):
+    n = 512
+    t = Table({"k": jnp.asarray(rng.integers(0, 1 << 20, n).astype(np.int32)),
+               "v": jnp.arange(n, dtype=jnp.int32)})
+    run = {"order_by": lambda: order_by(t, "k"),
+           "distinct": lambda: distinct(t, "k"),
+           "top_k": lambda: top_k(t, "k", 5),
+           "sort_merge_join": lambda: sort_merge_join(t, t, "k")}[op]
+    moved, tr = _moved_and_traced(run)
+    fetches = tr.find("query.fetch")
+    assert moved > 0 and tr.total("query.fetch", "bytes") == moved
+    (scope,) = tr.find(f"query.{op}")
+    assert all(scope["t0"] <= s["t0"] and s["t1"] <= scope["t1"]
+               for s in fetches)
+    assert not tr.find("query.gather") and not tr.find("query.reduce")
