@@ -11,15 +11,16 @@ Pipeline for ``n`` keys of ``p`` bits with trie depth ``l_n``:
    counting / cross-chunk-scan / parallel-placement structure of Stehle &
    Jacobsen's hybrid radix and Wassenberg & Sanders' bandwidth-bounded
    radix).  Phase 1 builds every fixed-size chunk's digit histogram at
-   once (a vmapped bincount — no sequential dependence); phase 2 derives
-   every chunk's carry from *one* exclusive scan over the
-   ``(num_chunks, n_bins)`` histogram matrix and then ranks all chunks in
-   parallel (``vmap``), the intra-chunk arrival coming from a one-hot
-   cumulative sum — on TPU an MXU matmul, and on CPU free of the serial
-   chunk-to-chunk dependence the old ``lax.scan`` imposed.  The streaming
-   carry API (``carry_in``/``carry_out``/``bin_start``) is unchanged, so
-   batched and distributed consumers stream slices through one cached
-   histogram exactly as before (paper §III.C/D).
+   once (an int32 sum of the chunk's one-hot hit mask — no sequential
+   dependence); phase 2 derives every chunk's carry from one exclusive
+   scan over the ``(chunks, n_bins)`` histogram matrix and ranks all
+   chunks in parallel, the intra-chunk arrival coming from one MXU
+   matmul of the hit mask against a strict-triangular matrix (the Pallas
+   rank kernel's formulation) and each per-key pick from a masked sum
+   over the bins, not a gather.  The streaming carry API
+   (``carry_in``/``carry_out``/``bin_start``) lets batched and
+   distributed consumers stream slices through one cached histogram
+   (paper §III.C/D).
 3. **Reconstruct** (Algorithm 5 / FractalSortCPUA) — the sorted array is
    rebuilt from (bin counts, per-bin stable order, trailing bits).  The top
    ``l_n`` bits of every output key are *recovered from the bin position*,
@@ -68,6 +69,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import fractal_tree as ft
 from repro.core.executor import JnpBackend, PlanExecutor
@@ -226,13 +228,26 @@ def _rank_empty(n_bins, carry_in, bin_start):
     return jnp.zeros((0,), jnp.int32), counts, carry_in
 
 
-# Per-group cap on the materialized (chunks x chunk x n_bins) one-hot
-# footprint of the chunk-parallel rank, in int32 elements (2**19 = 2 MiB):
-# groups this size stay LLC-resident on the host while still exposing
-# many chunks of parallelism per step (measured fastest on this 2-core
-# host across n in 2^15..2^18, bins in 16..256 — see bench_sortplan's
-# rank-engine comparison mode).
-_RANK_GROUP_ELEMS = 1 << 19
+# Chunk length cap of the one-hot engine: one MXU weight tile of the
+# strict-triangular arrival matrix.  It also keeps every chunk histogram
+# (<= 128) exact in bfloat16, the operand of the in-group chunk scan.
+_RANK_CHUNK = 128
+
+# Per-group caps of the chunk-parallel rank: the (chunks x n_bins x chunk)
+# hit mask in elements, and the chunks, whose (chunks, chunks) triangle
+# the in-group chunk scan multiplies by.  Each group is one step of the
+# sequential scan, so larger groups cut the per-step overhead: 2**21
+# elements (1024 chunks of 128 at 16 bins) measured faster on a TPU v5e
+# than 2**19, or 2**22 with 2048 chunks, and faster on the host than
+# 2**19.
+_RANK_GROUP_ELEMS = 1 << 21
+_RANK_GROUP_CHUNKS = 1 << 10
+
+
+def _strictly_before(m: int) -> jnp.ndarray:
+    """``(m, m)`` bfloat16 matrix with ``[j, i] = 1`` where ``j < i``."""
+    pos = jnp.arange(m, dtype=jnp.int32)
+    return (pos[:, None] < pos[None, :]).astype(jnp.bfloat16)
 
 
 def fractal_rank(
@@ -246,21 +261,30 @@ def fractal_rank(
 
     ``rank[i] = bin_start[prefix[i]] + carry[prefix[i]] + arrivals before i``
     — the scatter-index computation of a counting/radix sort, evaluated by
-    the **two-phase chunk-parallel engine**:
+    the **two-phase chunk-parallel engine** over fixed-size chunks (at most
+    ``_RANK_CHUNK`` keys, and no more than ``batch`` allows), in groups of
+    at most ``_RANK_GROUP_CHUNKS`` chunks whose hit mask holds at most
+    about ``_RANK_GROUP_ELEMS`` elements.  For each group, from its
+    lane-dense ``(chunks, n_bins, chunk)`` hit mask:
 
-    * phase 1: every chunk's digit histogram (the last row of the chunk's
-      one-hot cumulative sum — computed once, no sequential dependence
-      between chunks);
-    * phase 2: every chunk's carry from one exclusive scan over the
-      ``(num_chunks, n_bins)`` histogram matrix, then all chunks ranked in
-      parallel (vmapped one-hot cumulative sum for the intra-chunk
-      arrival).
+    * every key's intra-chunk arrival is one MXU matmul of all
+      ``chunks * n_bins`` hit rows against the strict-triangular
+      ``(chunk, chunk)`` matrix — bfloat16 0/1 operands, float32
+      accumulation, exact for counts below 2**24 — picked per key by a
+      masked sum over the bins;
+    * every chunk's histogram is an int32 sum of the mask over positions;
+    * every chunk's carry is the group's running int32 carry plus an
+      exclusive scan of the histograms over the group's chunks (a second,
+      small triangular matmul: its values are bounded by the group's key
+      count), picked per key by an int32 masked sum over the bins —
+      carries reach ``n``, past float32's exact range, so they never pass
+      through a matmul.
 
-    Chunks are processed in LLC-sized *groups* (``_RANK_GROUP_ELEMS``):
-    within a group everything is vmapped (parallel); only the tiny
-    ``(n_bins,)`` carry crosses group boundaries.  When the whole input
-    fits one group — every default-plan pass up to ``n = 2**19`` — there
-    is no sequential step at all.
+    Inside the group scan nothing is gathered and nothing is a cumulative
+    sum over positions (on TPU a cumsum lowers to a reduce-window as long
+    as its axis).  Only the tiny ``(n_bins,)`` carry crosses group
+    boundaries (a ``lax.scan``); when the whole input fits one group
+    there is no sequential step at all.
 
     ``carry_in`` lets callers stream several key batches through one
     cached histogram (paper §III.D); ``bin_start`` may be supplied when
@@ -280,37 +304,32 @@ def fractal_rank(
     # Inherit the data's varying-manual-axes so the group-scan carry
     # typechecks under shard_map (VMA tracking); no-op numerically.
     carry_in = carry_in + prefix[0] * 0
-    chunks = _rank_chunks(prefix, n, n_bins, batch)
+    chunks = _rank_chunks(prefix, n, n_bins, min(batch, _RANK_CHUNK))
     num_chunks, chunk_len = chunks.shape
-    group = min(num_chunks,
+    group = min(num_chunks, _RANK_GROUP_CHUNKS,
                 max(1, _RANK_GROUP_ELEMS // (chunk_len * n_bins)))
     gpad = (-num_chunks) % group
     if gpad:  # sentinel chunks: contribute nothing, ranks sliced off
         chunks = jnp.concatenate(
             [chunks, jnp.full((gpad, chunk_len), n_bins, jnp.int32)])
     groups = chunks.reshape(-1, group, chunk_len)
-    bins = jnp.arange(n_bins, dtype=jnp.int32)
-
-    def chunk_stats(chunk):
-        # one-hot (chunk, n_bins): on TPU this feeds the MXU (ones @ onehot
-        # for counts, strict-lower-triangular @ onehot for arrivals).  The
-        # final cumsum row *is* the chunk histogram — phase 1 and the
-        # intra-chunk arrival share one one-hot materialization.
-        onehot = (chunk[:, None] == bins[None, :]).astype(jnp.int32)
-        cum = jnp.cumsum(onehot, axis=0)
-        safe = jnp.clip(chunk, 0, n_bins - 1)
-        intra = jnp.take_along_axis(cum - onehot, safe[:, None], axis=1)[:, 0]
-        return intra, cum[-1]
+    # a constant, not an iota: XLA's CPU fusions of the mask run twice as
+    # fast against it
+    bins = np.arange(n_bins, dtype=np.int32)
 
     def group_body(carry, gchunks):
-        # phase 1: all chunk histograms in this group at once
-        intra, hists = jax.vmap(chunk_stats)(gchunks)
-        # phase 2: every chunk's carry from one exclusive scan, then all
-        # chunks ranked in parallel
-        chunk_carry = carry[None, :] + jnp.cumsum(hists, axis=0) - hists
-        base = jax.vmap(
-            lambda ch, c: c[jnp.clip(ch, 0, n_bins - 1)])(gchunks, chunk_carry)
-        return carry + hists.sum(axis=0), base + intra
+        hit = gchunks[:, None, :] == bins[None, :, None]
+        running = jnp.einsum("cbj,ji->cbi", hit.astype(jnp.bfloat16),
+                             _strictly_before(chunk_len),
+                             preferred_element_type=jnp.float32)
+        intra = jnp.sum(jnp.where(hit, running, 0.0), axis=1)
+        hists = jnp.sum(hit, axis=2, dtype=jnp.int32)
+        earlier = jnp.einsum("cb,cd->db", hists.astype(jnp.bfloat16),
+                             _strictly_before(group),
+                             preferred_element_type=jnp.float32)
+        chunk_carry = carry[None, :] + earlier.astype(jnp.int32)
+        base = jnp.sum(jnp.where(hit, chunk_carry[:, :, None], 0), axis=1)
+        return carry + hists.sum(axis=0), base + intra.astype(jnp.int32)
 
     carry_out, ranks = jax.lax.scan(group_body, carry_in, groups)
     ranks = ranks.reshape(-1)[:n]

@@ -47,12 +47,13 @@ def _assert_rank_triples_equal(a, b, ctx):
 
 
 @pytest.mark.parametrize("engine", ENGINE_FNS, ids=ENGINE_IDS)
-@pytest.mark.parametrize("n", [1, 17, 63, 64, 65, 1000, 4097])
-@pytest.mark.parametrize("n_bins", [2, 16, 256])
+@pytest.mark.parametrize("n", [1, 17, 63, 64, 65, 1000, 4097, 70001])
+@pytest.mark.parametrize("n_bins", [2, 16, 256, 8])
 def test_parallel_rank_matches_serial_across_chunk_boundaries(
         rng, engine, n, n_bins):
     """Non-divisible sizes: chunk/tile (batch=64) and group boundaries
-    land mid-stream; the carry handoff must be exact at every boundary."""
+    land mid-stream (70001 keys span several one-hot groups at every
+    width); the carry handoff must be exact at every boundary."""
     d = jnp.asarray(rng.integers(0, n_bins, n).astype(np.int32))
     _assert_rank_triples_equal(
         engine(d, n_bins, batch=64),
@@ -60,10 +61,13 @@ def test_parallel_rank_matches_serial_across_chunk_boundaries(
 
 
 @pytest.mark.parametrize("engine", ENGINE_FNS, ids=ENGINE_IDS)
-@pytest.mark.parametrize("dist", ["all_equal", "two_hot", "ramp"])
+@pytest.mark.parametrize("dist", ["all_equal", "two_hot", "ramp",
+                                  "all_equal_groups"])
 def test_parallel_rank_matches_serial_adversarial(rng, engine, dist):
-    n, n_bins = 5000, 16
-    if dist == "all_equal":
+    """All-equal keys drive every chunk's arrival count to chunk - 1;
+    ``all_equal_groups`` runs them through several one-hot groups."""
+    n, n_bins = (98309 if dist == "all_equal_groups" else 5000), 16
+    if dist.startswith("all_equal"):
         d = np.full(n, 7, np.int32)
     elif dist == "two_hot":
         d = np.where(rng.random(n) < 0.95, 3, 12).astype(np.int32)
@@ -78,13 +82,17 @@ def test_parallel_rank_matches_serial_adversarial(rng, engine, dist):
 @pytest.mark.parametrize("engine", ENGINE_FNS, ids=ENGINE_IDS)
 def test_parallel_rank_streaming_carry_and_bin_start(rng, engine):
     """carry_in/bin_start injection (the streaming + distributed modes)
-    must thread identically through every engine."""
+    must thread identically through every engine.  Carries and starts
+    past 2**24 (odd ones included) would round through float32: the
+    one-hot engine must keep them int32."""
     n_bins = 16
     d = jnp.asarray(rng.integers(0, n_bins, 3000).astype(np.int32))
     ci = jnp.asarray(rng.integers(0, 50, n_bins).astype(np.int32))
     bs = jnp.asarray(rng.integers(0, 100, n_bins).astype(np.int32))
+    big = 1 << 25
     for kw in ({"carry_in": ci}, {"bin_start": bs},
-               {"carry_in": ci, "bin_start": bs}):
+               {"carry_in": ci, "bin_start": bs},
+               {"carry_in": ci + big + 1, "bin_start": bs + 2 * big + 1}):
         _assert_rank_triples_equal(engine(d, n_bins, batch=64, **kw),
                                    fractal_rank_serial(d, n_bins, batch=64,
                                                        **kw), list(kw))

@@ -8,6 +8,7 @@ only one process at a time may load the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -95,6 +96,46 @@ def test_fractal_sort_compiles(one_chip):
     c = _compile(one_chip, lambda k: fractal_sort(k, 32, plan=plan),
                  ((N,), jnp.uint32))
     assert c.memory_analysis().temp_size_in_bytes < 16 * N
+
+
+def _computations(hlo: str) -> dict:
+    """Computation name -> its instruction lines, from HLO text."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            name = re.search(r"%[\w.\-]+", line).group(0)
+            comps[name] = []
+        elif line == "}":
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return comps
+
+
+def test_rank_scan_holds_no_gather_or_reduce_window(one_chip):
+    """The one-hot rank's group scan works on masks and matmuls: the while
+    bodies of the default p=16 sort, and every computation they call,
+    hold no reduce-window (a cumsum lowers to one on TPU) and no gather.
+    The reconstruct's binary search (``searchsorted``) is a while loop
+    of its own, outside the rank, and is not checked."""
+    c = _compile(one_chip, lambda k: fractal_sort(k, 16), ((N,), jnp.int32))
+    comps = _computations(c.as_text())
+    todo = [m.group(1) for lines in comps.values() for line in lines
+            if " while(" in line and "searchsorted" not in line
+            for m in [re.search(r"body=(%[\w.\-]+)", line)]]
+    assert len(todo) == len(make_sort_plan(N, 16).passes)
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            assert not re.search(r"\s(reduce-window|gather)\(", line), line
+            todo += [r for r in re.findall(r"%[\w.\-]+", line)
+                     if r in comps]
+    assert any(re.search(r"\s(convolution|dot)\(", line)
+               for name in seen for line in comps[name])
 
 
 def test_scatter_engine_raises_compiled():
