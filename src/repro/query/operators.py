@@ -175,6 +175,26 @@ def _resolve_plans(n: int, active, plans):
     return tuple(plans)
 
 
+def _argsort_chain(words, active, plans, argsort):
+    """``(words[perm], perm)``: one stable ``argsort(key, plan)`` per
+    active word, least significant first (the composition is
+    lexicographic, i.e. numeric on the full code).
+
+    Every word moves as its own 1-D column: a TPU lays a 2-D ``(n, W)``
+    uint32 array out in (8, 128) tiles, so gathering rows of an
+    ``(n, 1)`` code matrix would hold it at 128 lanes a row (a 26-bit
+    key at 2^25 rows needs 16 GiB of HBM that way, against 128 MiB as a
+    column)."""
+    n = words.shape[0]
+    cols = [words[:, j] for j in range(words.shape[1])]
+    perm = jnp.arange(n, dtype=jnp.int32)
+    for (j, _), plan in zip(reversed(active), reversed(plans)):
+        # plan covers the word's undetermined low bits; higher bits are
+        # row-invariant here, so digit passes never see them
+        perm = perm[argsort(cols[j][perm], plan)]
+    return jnp.stack([c[perm] for c in cols], axis=1), perm
+
+
 @functools.lru_cache(maxsize=256)
 def _rowid_chain(active: Tuple[Tuple[int, int], ...],
                  plans: Tuple[SortPlan, ...], pairs_path: bool):
@@ -206,13 +226,7 @@ def _rowid_chain(active: Tuple[Tuple[int, int], ...],
             sorted_keys, rowids = ex.run_pairs(
                 words[:, 0], jnp.arange(n, dtype=jnp.int32), plans[0])
             return sorted_keys.astype(jnp.uint32)[:, None], rowids
-        perm = jnp.arange(n, dtype=jnp.int32)
-        for (j, _), plan in zip(reversed(active), reversed(plans)):
-            # plan covers the word's undetermined low bits; higher bits
-            # are row-invariant here, so digit passes never see them
-            sub = ex.run_argsort(words[perm, j], plan)
-            perm = perm[sub]
-        return words[perm], perm
+        return _argsort_chain(words, active, plans, ex.run_argsort)
 
     return dispatch.wrap("query.chain", chain)
 
@@ -245,12 +259,8 @@ def _fused_chain(codec: CompositeCodec, active: Tuple[Tuple[int, int], ...],
                 prepped, jnp.arange(n, dtype=jnp.int32), plans[0],
                 encode=lambda pre: codec.encode_fn(pre)[:, 0])
             return sorted_keys.astype(jnp.uint32)[:, None], rowids
-        words = codec.encode_fn(prepped)
-        perm = jnp.arange(n, dtype=jnp.int32)
-        for (j, _), plan in zip(reversed(active), reversed(plans)):
-            sub = ex.run_argsort(words[perm, j], plan)
-            perm = perm[sub]
-        return words[perm], perm
+        return _argsort_chain(codec.encode_fn(prepped), active, plans,
+                              ex.run_argsort)
 
     return dispatch.wrap("query.chain", chain)
 
@@ -333,7 +343,12 @@ def sort_rowids_fused(codec: CompositeCodec, prepped,
     to the full-width sort while low-entropy keys shed most of their
     pass work.  Narrowed single-word sorts take the argsort path — the
     pairs path's MSD reconstruct rebuilds only the sorted bits and would
-    zero the shared high bits of the returned words."""
+    zero the shared high bits of the returned words.
+
+    Every chain it runs adds its rows to the ``query.rows_sorted``
+    counter, and to ``query.sort_min_bytes`` the least bytes the sort
+    must move: the prepared key columns read once and an int32 row id
+    written once a row."""
     n = jax.tree_util.tree_leaves(prepped)[0].shape[0]
     widths = word_widths(codec.bits)
     if n == 0:
@@ -349,6 +364,9 @@ def sort_rowids_fused(codec: CompositeCodec, prepped,
     plans = _resolve_plans(n, active, plans)
     pairs_path = (len(widths) == 1 and len(active) == 1
                   and active[0][1] == widths[0])
+    key_bytes = sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(prepped))
+    metrics.counter("query.rows_sorted").inc(n)
+    metrics.counter("query.sort_min_bytes").inc(key_bytes + 4 * n)
     with trace.span("query.chain"):
         return _fused_chain(codec, active, plans, pairs_path)(prepped)
 
@@ -366,14 +384,11 @@ def _segmented_chain(active: Tuple[Tuple[int, int], ...],
 
     @jax.jit
     def chain(words):
-        n = words.shape[0]
         ex = PlanExecutor(JnpBackend())
-        perm = jnp.arange(n, dtype=jnp.int32)
-        for (j, _), plan in zip(reversed(active), reversed(plans)):
-            sub = ex.run_segmented_argsort(words[perm, j], plan,
-                                           seg_len_log2)
-            perm = perm[sub]
-        return words[perm], perm
+        return _argsort_chain(
+            words, active, plans,
+            lambda key, plan: ex.run_segmented_argsort(key, plan,
+                                                       seg_len_log2))
 
     return dispatch.wrap("query.segmented_chain", chain)
 
@@ -481,7 +496,9 @@ def top_k(table: Table, by, k: int,
     candidate sort is the global stable sort restricted to a prefix-closed
     key range.  ``plans`` applies when the sort runs over all ``n`` rows
     (k >= n, or no pruning opportunity); a pruned candidate subset
-    re-resolves tuned plans for its own (smaller) length.
+    re-resolves tuned plans for its own (smaller) length.  The histogram
+    and the candidate pick are the span ``query.prune``, the final
+    gather ``query.take``.
     """
     stream = _stream_ops(table)
     if stream is not None:
@@ -501,24 +518,30 @@ def top_k(table: Table, by, k: int,
 def _top_k_mem(table: Table, by, k: int, codecs, plans) -> Table:
     codec, prepped = _key_data(table, by, codecs)
     n = jax.tree_util.tree_leaves(prepped)[0].shape[0]
+    sub_pre = None
     if k < n:
-        top_bits = min(_TOPK_PRUNE_BITS, word_widths(codec.bits)[0])
-        shift = word_widths(codec.bits)[0] - top_bits
-        # one jitted dispatch: fused encode → leading-digit histogram
-        counts, prefix = _prune_hist(codec, top_bits, shift)(prepped)
-        cut = jnp.searchsorted(jnp.cumsum(counts), k, side="left")
-        keep = prefix <= cut
-        # the host sync: the candidate count sizes the nonzero
-        size = int(_fetch(jnp.sum(keep, dtype=jnp.int32)))
-        rows = jnp.nonzero(keep, size=size)[0].astype(jnp.int32)
-        if rows.shape[0] < n:
-            # the candidate subset re-resolves its own (tuned) plans:
-            # caller-pinned plans were sized for n rows, not ~k
-            sub_pre = jax.tree_util.tree_map(lambda a: a[rows], prepped)
-            _, sub = sort_rowids_fused(codec, sub_pre)
-            return table.take(rows[sub[:k]])
-    _, rowids = sort_rowids_fused(codec, prepped, plans)
-    return table.take(rowids[:k])
+        with trace.span("query.prune"):
+            top_bits = min(_TOPK_PRUNE_BITS, word_widths(codec.bits)[0])
+            shift = word_widths(codec.bits)[0] - top_bits
+            # one jitted dispatch: fused encode → leading-digit histogram
+            counts, prefix = _prune_hist(codec, top_bits, shift)(prepped)
+            cut = jnp.searchsorted(jnp.cumsum(counts), k, side="left")
+            keep = prefix <= cut
+            # the host sync: the candidate count sizes the nonzero
+            size = int(_fetch(jnp.sum(keep, dtype=jnp.int32)))
+            rows = jnp.nonzero(keep, size=size)[0].astype(jnp.int32)
+            if size < n:
+                sub_pre = jax.tree_util.tree_map(lambda a: a[rows], prepped)
+    if sub_pre is not None:
+        # the candidate subset re-resolves its own (tuned) plans:
+        # caller-pinned plans were sized for n rows, not ~k
+        _, sub = sort_rowids_fused(codec, sub_pre)
+        picked = rows[sub[:k]]
+    else:
+        _, rowids = sort_rowids_fused(codec, prepped, plans)
+        picked = rowids[:k]
+    with trace.span("query.take"):
+        return table.take(picked)
 
 
 def _words_searchsorted(sorted_words: np.ndarray, queries: np.ndarray,
@@ -669,6 +692,11 @@ def sort_merge_join(left: Table, right: Table, on,
     ``plans`` (one per code word) applies to *both* sides' sorts; leave
     it None when the two tables differ widely in size so each side
     resolves its own tuned plan.
+
+    Its host phases are the spans ``query.merge`` (the two probes),
+    ``query.expand`` (the repeat and position arrays) and ``query.take``
+    (both sides' gathers); the ``query.join_rows_out`` counter adds the
+    output's rows.
     """
     assert isinstance(left, Table) and isinstance(right, Table), (
         "sort_merge_join is in-memory only (a streaming join over "
@@ -692,16 +720,21 @@ def _join_mem(left: Table, right: Table, on, by, codecs, suffixes,
     lc, lrid = sort_rowids_fused(codec_l, pre_l, plans)
     rc, rrid = sort_rowids_fused(codec_r, pre_r, plans)
     lc, rc = _fetch(lc), _fetch(rc)
-    lo = _words_searchsorted(rc, lc, side="left")
-    hi = _words_searchsorted(rc, lc, side="right")
-    cnt = hi - lo
-    total = int(cnt.sum())
-    lpos = np.repeat(np.arange(cnt.shape[0]), cnt)
-    seg_start = np.repeat(np.cumsum(cnt) - cnt, cnt)
-    rpos = np.asarray(lo)[lpos] + (np.arange(total) - seg_start)
-    lrows = jnp.asarray(_fetch(lrid)[lpos])
-    rrows = jnp.asarray(_fetch(rrid)[rpos])
-    ltab, rtab = left.take(lrows), right.take(rrows)
+    with trace.span("query.merge"):
+        lo = _words_searchsorted(rc, lc, side="left")
+        hi = _words_searchsorted(rc, lc, side="right")
+    with trace.span("query.expand"):
+        cnt = hi - lo
+        total = int(cnt.sum())
+        lpos = np.repeat(np.arange(cnt.shape[0]), cnt)
+        seg_start = np.repeat(np.cumsum(cnt) - cnt, cnt)
+        rpos = np.asarray(lo)[lpos] + (np.arange(total) - seg_start)
+    metrics.counter("query.join_rows_out").inc(total)
+    with trace.span("query.take", rows=total):
+        # host row ids: device columns gather on the device, host
+        # (float64) columns in numpy, with no round trip of the ids
+        ltab = left.take(_fetch(lrid)[lpos])
+        rtab = right.take(_fetch(rrid)[rpos])
     keys = {name for name, _ in by}
     out = {name: ltab.column(name) for name, _ in by}
     for name in left.column_names:

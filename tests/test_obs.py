@@ -437,3 +437,34 @@ def test_span_holds_profiler_annotation_of_its_name(monkeypatch):
     assert seen == [("enter", "outer"), ("enter", "inner"),
                     ("exit", "inner"), ("exit", "outer")]
     assert session.trace.find("outer")[0]["attrs"] == {"bytes": 3}
+
+
+@pytest.mark.parametrize("op", ["sort_merge_join", "top_k"])
+def test_join_and_top_k_phases_nest_under_their_operator(op):
+    """``sort_merge_join``'s host phases (``query.merge``,
+    ``query.expand``, ``query.take``) and ``top_k``'s (``query.prune``,
+    ``query.take``) are spans under the operator's own scope, beside the
+    sort's ``query.probe`` and ``query.chain``."""
+    from repro.query import Table, sort_merge_join, top_k
+
+    rng = np.random.default_rng(11)
+    n = 600
+    t = Table({"k": jnp.asarray(rng.integers(0, 50, n).astype(np.int32)),
+               "v": rng.standard_normal(n)})
+    run = {"sort_merge_join": lambda: sort_merge_join(t, t.head(90), "k"),
+           "top_k": lambda: top_k(t, [("v", "desc")], 7)}[op]
+    phases = {"sort_merge_join": {"query.merge", "query.expand",
+                                  "query.take"},
+              "top_k": {"query.prune", "query.take"}}[op]
+    with obs.tracing() as session:
+        out = run()
+    tr = session.trace
+    tr.assert_well_formed()
+    (scope,) = tr.find(f"query.{op}")
+    children = tr.summary()[f"query.{op}"]["children"]
+    assert phases | {"query.probe", "query.chain"} <= set(children)
+    for name in phases:
+        (s,) = tr.find(name)
+        assert s["parent"] == scope["sid"]
+    if op == "sort_merge_join":
+        assert tr.total("query.take", "rows") == len(out) > 0

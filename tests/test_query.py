@@ -641,3 +641,124 @@ def test_operators_count_their_fetches(rng, op):
     assert all(scope["t0"] <= s["t0"] and s["t1"] <= scope["t1"]
                for s in fetches)
     assert not tr.find("query.gather") and not tr.find("query.reduce")
+
+
+# --- a TPC-H Q3-shaped plan: join -> join -> group_by -> top_k ---------------
+
+
+def _q3_tables(rng, dist):
+    """customer-, orders- and lineitem-like tables: ``a`` joins the first
+    two, ``b`` the second to the third; float64 ``x`` takes whole values
+    so that sums are exact and revenues tie."""
+    if dist == "duplicate_heavy":
+        ka, kb = 8, 30
+    else:  # sparse keys over the widths the codecs declare
+        ka, kb = 1 << 21, 1 << 26
+    n_a, n_b, n_c = 300, 500, 2000
+    b_keys = rng.integers(0, kb, n_b).astype(np.int32)
+    A = Table({"a": jnp.asarray(rng.integers(0, ka, n_a).astype(np.int32))})
+    a_of_b = (np.asarray(A.column("a"))[rng.integers(0, n_a, n_b)]
+              if dist != "duplicate_heavy"
+              else rng.integers(0, ka, n_b).astype(np.int32))
+    B = Table({"a": jnp.asarray(a_of_b),
+               "b": jnp.asarray(b_keys),
+               "d": jnp.asarray(rng.integers(0, 20, n_b).astype(np.int32)),
+               "p": jnp.asarray(rng.integers(0, 2, n_b).astype(np.int32))})
+    C = Table({"b": jnp.asarray(b_keys[rng.integers(0, n_b, n_c)]),
+               "x": rng.integers(1, 4, n_c).astype(np.float64)})
+    return A, B, C
+
+
+def _q3_oracle(A, B, C, k):
+    a = np.asarray(A.column("a"))
+    ba, bb, bd, bp = (np.asarray(B.column(c)).astype(np.int64)
+                      for c in ("a", "b", "d", "p"))
+    cb, cx = np.asarray(C.column("b")).astype(np.int64), C.column("x")
+    count_a = np.bincount(a, minlength=int(ba.max()) + 1)[ba]
+    rows, inv = np.unique(cb, return_inverse=True)
+    at = np.minimum(np.searchsorted(rows, bb), rows.size - 1)
+    hit = rows[at] == bb
+    sum_c = np.where(hit, np.bincount(inv, cx)[at], 0.0)
+    cnt_c = np.where(hit, np.bincount(inv)[at], 0)
+    keys, ginv = np.unique(np.stack([bb, bd, bp], 1), axis=0,
+                           return_inverse=True)
+    ginv = ginv.ravel()
+    rev = np.bincount(ginv, count_a * sum_c, len(keys))
+    n = np.bincount(ginv, count_a * cnt_c, len(keys)).astype(np.int64)
+    keys, rev, n = keys[n > 0], rev[n > 0], n[n > 0]
+    top = np.lexsort((keys[:, 2], keys[:, 0], keys[:, 1], -rev))[:k]
+    return keys, rev, n, top
+
+
+@pytest.mark.parametrize("codecs", ["declared", "inferred"])
+@pytest.mark.parametrize("dist", ["duplicate_heavy", "sparse"])
+def test_q3_plan_matches_numpy_oracle(rng, dist, codecs):
+    """Two joins, a three-column GROUP BY and a top-k by (sum desc, date)
+    match a numpy oracle exactly, row for row: every joined row counts
+    once (duplicate keys on both sides of both joins), and revenue ties
+    keep the GROUP BY's key order."""
+    A, B, C = _q3_tables(rng, dist)
+    cod = ({"a": UIntCodec(21), "b": UIntCodec(26), "d": UIntCodec(14),
+            "p": UIntCodec(1)} if codecs == "declared" else None)
+    j1 = sort_merge_join(A, B, "a", codecs=cod)
+    j2 = sort_merge_join(j1.select(["b", "d", "p"]), C, "b", codecs=cod)
+    g = group_by(j2, ["b", "d", "p"], {"rev": ("x", "sum"),
+                                      "n": (None, "count")}, codecs=cod)
+    top = top_k(g, [("rev", "desc"), "d"], 10, codecs=cod)
+    keys, rev, n, order = _q3_oracle(A, B, C, 10)
+    got = np.stack([np.asarray(g.column(c)).astype(np.int64)
+                    for c in ("b", "d", "p")], 1)
+    np.testing.assert_array_equal(got, keys)
+    np.testing.assert_array_equal(np.asarray(g.column("rev")), rev)
+    np.testing.assert_array_equal(np.asarray(g.column("n")), n)
+    assert len(j2) == n.sum()
+    got_top = np.stack([np.asarray(top.column(c)).astype(np.int64)
+                        for c in ("b", "d", "p")], 1)
+    np.testing.assert_array_equal(got_top, keys[order])
+    np.testing.assert_array_equal(np.asarray(top.column("rev")), rev[order])
+    assert len(np.unique(rev[order])) < len(order)  # the top holds ties
+
+
+def _counted(fn):
+    from repro.obs import metrics
+
+    before = metrics.snapshot()
+    out = fn()
+    return out, metrics.snapshot_delta(before)
+
+
+def test_join_counts_rows_sorted_and_out(rng):
+    """``query.rows_sorted`` adds both sides' rows, ``query.sort_min_bytes``
+    their int32 keys and row ids (8 B a row), ``query.join_rows_out`` the
+    output's rows."""
+    left = Table({"k": jnp.asarray(rng.integers(0, 40, 700).astype(np.int32))})
+    right = Table({"k": jnp.asarray(rng.integers(0, 40, 300).astype(np.int32)),
+                   "v": rng.standard_normal(300)})
+    out, d = _counted(lambda: sort_merge_join(left, right, "k"))
+    assert d["query.rows_sorted"] == 1000
+    assert d["query.sort_min_bytes"] == 1000 * (4 + 4)
+    assert d["query.join_rows_out"] == len(out) > 1000
+
+
+@pytest.mark.parametrize("op", ["group_by", "top_k", "empty"])
+def test_sort_counters_follow_the_chains_that_ran(rng, op):
+    """A float64 key is 8 prepared bytes a row and a uint16 one 2; a
+    pruned top-k sorts only its candidates; an empty table runs no
+    chain and counts nothing."""
+    n = 4000
+    t = Table({"f": rng.standard_normal(n),
+               "u": jnp.asarray(rng.integers(0, 1 << 16, n).astype(np.uint16))})
+    if op == "group_by":
+        _, d = _counted(lambda: group_by(t, ["f", "u"], {"c": (None, "count")}))
+        assert d["query.rows_sorted"] == n
+        assert d["query.sort_min_bytes"] == n * (8 + 2 + 4)
+    elif op == "top_k":
+        _, d = _counted(lambda: top_k(t, "u", 5))
+        rows = d["query.rows_sorted"]
+        assert 5 <= rows < n
+        assert d["query.sort_min_bytes"] == rows * (2 + 4)
+    else:
+        _, d = _counted(lambda: group_by(t.head(0), "u",
+                                         {"c": (None, "count")}))
+        assert "query.rows_sorted" not in d
+        assert "query.sort_min_bytes" not in d

@@ -22,6 +22,8 @@ from repro.kernels.fractal_histogram import fractal_histogram
 from repro.kernels.fractal_rank import fractal_rank_counts, fractal_rank_kernel
 from repro.kernels.fractal_reconstruct import fractal_reconstruct
 from repro.kernels.moe_dispatch import moe_dispatch
+from repro.query.codec import ColumnSpec, CompositeCodec, UIntCodec
+from repro.query.operators import _fused_chain
 
 N = 1 << 20
 
@@ -136,6 +138,25 @@ def test_rank_scan_holds_no_gather_or_reduce_window(one_chip):
                      if r in comps]
     assert any(re.search(r"\s(convolution|dot)\(", line)
                for name in seen for line in comps[name])
+
+
+@pytest.mark.parametrize("active", [26, 25])
+def test_join_key_sort_fits_a_chip(one_chip, active):
+    """The 26-bit orderkey sort of TPC-H Q3's second join at 2^25 rows
+    (LINEITEM's 32.3M kept rows at SF 10 round up to it): at full width
+    the pairs path, narrowed to 25 varying bits the argsort path.  Each
+    holds its code words as 1-D columns, a few bytes a row: a gathered
+    ``(n, 1)`` uint32 matrix is laid out 128 lanes a row on a TPU, 16 GiB
+    here, more than the chip holds."""
+    n = 1 << 25
+    codec = CompositeCodec([ColumnSpec(UIntCodec(26))])
+    chain = _fused_chain(codec, ((0, active),),
+                         (make_sort_plan(n, active),), active == 26)
+    c = _compile(one_chip, lambda k: chain.__wrapped__((k,)),
+                 ((n,), jnp.int32))
+    mem = c.memory_analysis()
+    assert mem.output_size_in_bytes <= 8 * n + 1024  # sorted words, row ids
+    assert mem.temp_size_in_bytes < 24 * n
 
 
 def test_scatter_engine_raises_compiled():
