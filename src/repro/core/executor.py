@@ -73,6 +73,7 @@ __all__ = [
     "PallasBackend",
     "DistributedBackend",
     "PlanExecutor",
+    "sort_placement",
 ]
 
 
@@ -95,6 +96,30 @@ def _as_key_stream(keys, encode) -> jnp.ndarray:
     if encode is None:
         return keys.astype(jnp.uint32)
     return encode(keys).astype(jnp.uint32)
+
+
+#: fewest rows a TPU placement sorts.  XLA's TPU scatter itself sorts by
+#: the indices from ~4.1M rows up (none at 4,050,000 rows, a sort at
+#: 4,100,000; compiled for a v5e), and there one sort of all the arrays
+#: saves its scatter fusions.  Below, the scatter is a native fusion, and
+#: a sort would add 15-26 s of TPU compile for each new n (2 or 3
+#: operands at 1,460,000 rows, against ~2 s): a set-up cost that shapes
+#: following the data, as a join's output, pay on every new input.
+SORT_PLACEMENT_MIN_ROWS = 1 << 22
+
+
+def _scatter_placement(rank: jnp.ndarray, *arrays: jnp.ndarray) -> tuple:
+    """Each array's elements placed at their ranks, one scatter an array."""
+    return tuple(jnp.zeros_like(a).at[rank].set(a) for a in arrays)
+
+
+def sort_placement(rank: jnp.ndarray, *arrays: jnp.ndarray) -> tuple:
+    """Each array's elements placed at their ranks, all arrays by one sort
+    keyed by rank.  A pass's ranks are a permutation of ``[0, n)``, so
+    the arrays in rank order are the placed arrays.  The ranks are
+    unique, so the sort need not be stable."""
+    return tuple(jax.lax.sort((rank, *arrays), num_keys=1,
+                              is_stable=False)[1:])
 
 
 class PassBackend:
@@ -151,11 +176,22 @@ class PassBackend:
     def scatter(self, rank: jnp.ndarray, *arrays: jnp.ndarray):
         """Place each array's elements at their ranks (payload carry).
 
-        The barrier keeps the rank arithmetic out of the scatter's
+        The barrier keeps the rank arithmetic out of the placement's
         fusion: fused, the TPU compile of one pass grows with n (100 s
-        at 2**26 keys on v5e, against 16 s unfused)."""
+        at 2**26 keys on v5e, against 16 s unfused).
+
+        Lowered for TPU, 1-D arrays of at least
+        :data:`SORT_PLACEMENT_MIN_ROWS` all ride one
+        :func:`sort_placement`: there XLA lowers a scatter to a sort by
+        the indices and a scatter of the sorted values into the slots
+        they already hold, once per array.  Elsewhere XLA's scatter is a
+        direct O(n) store loop, which a sort would only slow."""
         rank, arrays = jax.lax.optimization_barrier((rank, arrays))
-        return tuple(jnp.zeros_like(a).at[rank].set(a) for a in arrays)
+        if (rank.shape[0] < SORT_PLACEMENT_MIN_ROWS
+                or any(a.shape != rank.shape for a in arrays)):
+            return _scatter_placement(rank, *arrays)
+        return jax.lax.platform_dependent(rank, *arrays, tpu=sort_placement,
+                                          default=_scatter_placement)
 
     def lsd_pass(self, u: jnp.ndarray, dp: DigitPass) -> jnp.ndarray:
         """One stable counting pass scattering the full keys by a digit."""

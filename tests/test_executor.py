@@ -29,6 +29,7 @@ from repro.core import (
     fractal_sort_pairs,
     make_sort_plan,
 )
+from repro.core.executor import sort_placement
 
 # Both parallel engines are property-tested against the same serial-scan
 # oracle: same contract, one-hot vs sorted-tile arithmetic.
@@ -290,6 +291,71 @@ def test_argsort_duplicate_stability_across_backends(rng, dist, backend):
         perm, ov = distributed_fractal_argsort(arr, mesh, "data", p)
         assert not bool(ov)
     np.testing.assert_array_equal(np.asarray(perm), want, err_msg=dist)
+
+
+# --- the TPU placement: one sort keyed by rank --------------------------------
+
+
+class _SortPlacementBackend(JnpBackend):
+    """JnpBackend placing by :func:`sort_placement` on any platform (the
+    executor picks it only where the program is lowered for a TPU)."""
+
+    def scatter(self, rank, *arrays):
+        return sort_placement(rank, *arrays)
+
+
+def _placement_case(rng, mode, n):
+    """``(fn, args)`` of one placement case; ``fn(executor, *args)``."""
+    if mode.startswith("place:"):
+        rank = jnp.asarray(rng.permutation(n).astype(np.int32))
+        arrays = [jnp.asarray(rng.integers(0, 1 << 32, n, dtype=np.uint64)
+                              .astype(np.uint32).view(dt))
+                  for dt in mode[len("place:"):].split(",")]
+        return (lambda ex, *a: ex.backend.scatter(*a)), (rank, *arrays)
+    # duplicate-heavy 12-bit keys over 3 passes (two LSD, the MSD), so
+    # every pass's ranks order many equal digits by arrival
+    p = 12
+    plan = make_sort_plan(n, p)
+    keys = rng.choice([3, 0x0F3, 0x5A0, 0xFFF], n).astype(np.uint32)
+    if mode == "run_grouped_trailing":
+        t = plan.trailing_bits
+        grouped = np.sort(keys)
+        counts = np.bincount(grouped >> t, minlength=1 << plan.depth)
+        entries = grouped & np.uint32((1 << t) - 1)
+        for s, c in zip(np.cumsum(counts) - counts, counts):
+            entries[s:s + c] = rng.permutation(entries[s:s + c])
+        return (lambda ex, e, c: ex.run_grouped_trailing(e, c, plan),
+                (jnp.asarray(entries), jnp.asarray(counts.astype(np.int32))))
+    k = jnp.asarray(keys)
+    if mode == "run_pairs":
+        vals = (jnp.asarray(rng.integers(0, 1 << 30, n).astype(np.int32)),
+                jnp.asarray(keys ^ np.uint32(0xDEADBEEF)))
+        return (lambda ex, k, v: ex.run_pairs(k, v, plan)), (k, vals)
+    if mode == "run_segmented_argsort":
+        seg = min(3, (n & -n).bit_length() - 1)
+        return (lambda ex, k: ex.run_segmented_argsort(k, plan, seg)), (k,)
+    return (lambda ex, k: getattr(ex, mode)(k, plan)), (k,)
+
+
+@pytest.mark.parametrize("n", [1, 1000, 4099])
+@pytest.mark.parametrize("mode", [
+    "place:int32", "place:uint32", "place:uint32,int32", "run",
+    "run_pairs", "run_argsort", "run_segmented_argsort",
+    "run_grouped_trailing"])
+def test_sort_placement_matches_scatter(rng, mode, n):
+    """The TPU's placement (one sort of the ranks and every carried
+    array) gives, bit for bit, what the scatter gives: called directly on
+    a permutation, and through every executor mode that places with
+    ranks, on duplicate-heavy keys, so the ranks carry the stability."""
+    fn, args = _placement_case(rng, mode, n)
+    want = jax.tree_util.tree_leaves(fn(PlanExecutor(JnpBackend()), *args))
+    got = jax.tree_util.tree_leaves(
+        fn(PlanExecutor(_SortPlacementBackend()), *args))
+    assert len(got) == len(want)
+    for w, g in zip(want, got):
+        assert w.dtype == g.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=mode)
 
 
 # --- segment-aware grouped-trailing mode -------------------------------------

@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.executor import SORT_PLACEMENT_MIN_ROWS
 from repro.core.fractal_sort import fractal_sort
 from repro.core.sort_plan import make_sort_plan
 from repro.kernels.fractal_histogram import fractal_histogram
@@ -157,6 +158,39 @@ def test_join_key_sort_fits_a_chip(one_chip, active):
     mem = c.memory_analysis()
     assert mem.output_size_in_bytes <= 8 * n + 1024  # sorted words, row ids
     assert mem.temp_size_in_bytes < 24 * n
+
+
+@pytest.mark.parametrize("path,n", [("bulk", 1 << 23), ("pairs", 1 << 25),
+                                    ("pairs", 1_460_000)])
+def test_each_pass_places_by_one_sort(one_chip, path, n):
+    """Lowered for a TPU, a pass of ``SORT_PLACEMENT_MIN_ROWS`` rows or
+    more places its keys and payloads with one sort keyed by rank and no
+    scatter (which XLA lowers there to a sort plus a scatter of the
+    sorted values, once per array): the default p=16 sort holds one
+    two-operand sort a pass, the 26-bit pairs chain of Q3's second join
+    one three-operand sort (rank, key, row id) a pass.  Below, as for
+    the 1.46M rows of Q3's first join, each array keeps its native
+    scatter and the program holds no sort."""
+    if path == "bulk":
+        operands, passes = 2, len(make_sort_plan(n, 16).passes)
+        c = _compile(one_chip, lambda k: fractal_sort(k, 16),
+                     ((n,), jnp.int32))
+    else:
+        operands = 3
+        plan = make_sort_plan(n, 26)
+        passes = len(plan.passes)
+        chain = _fused_chain(CompositeCodec([ColumnSpec(UIntCodec(26))]),
+                             ((0, 26),), (plan,), True)
+        c = _compile(one_chip, lambda k: chain.__wrapped__((k,)),
+                     ((n,), jnp.int32))
+    hlo = c.as_text()
+    sorts = [len(args.split(","))
+             for args in re.findall(r"\ssort\(([^)]*)\)", hlo)]
+    scatters = len(re.findall(r"\sscatter\(", hlo))
+    if n >= SORT_PLACEMENT_MIN_ROWS:
+        assert (sorts, scatters) == ([operands] * passes, 0)
+    else:
+        assert (sorts, scatters) == ([], (operands - 1) * passes)
 
 
 def test_scatter_engine_raises_compiled():
