@@ -1,4 +1,4 @@
-"""SortPlan digit-width x rank-engine sweep: pick per-host plan defaults.
+"""SortPlan digit-width x rank-engine sweep.
 
 For each digit width w the plan runs ceil(p / w)-ish passes of 2**w bins.
 Under the *one-hot* engine rank work is O(n * 2**w * passes); under the
@@ -11,12 +11,6 @@ the analytic per-plan traffic next to the measured wall-clock.
 Modes (``python -m benchmarks.bench_sortplan <mode>``):
 
 * (default) — the engine x width sweep table.
-* ``tune`` — run :func:`~repro.core.autotune.autotune_plan` with
-  measurement forced over the standard shape buckets **and the query
-  layer's codec-driven widths** (9-bit ids, the 32-bit word of wide
-  composites), persisting the winners to the per-host cache every sort
-  entry point and query operator then resolves through.  This replaces
-  hand-picking ``DEFAULT_MAX_BINS_LOG2`` from the sweep table.
 * ``rank`` — rank-engine comparison on identical digit streams: the
   chunk-parallel one-hot :func:`fractal_rank` vs the sorted-tile
   :func:`fractal_rank_scatter` vs the serial-scan
@@ -41,7 +35,6 @@ from repro.core import (
     DEFAULT_MAX_BINS_LOG2,
     JnpBackend,
     PlanExecutor,
-    autotune_plan,
     fractal_rank,
     fractal_rank_scatter,
     fractal_rank_serial,
@@ -49,7 +42,6 @@ from repro.core import (
     fractal_sort_stats,
     make_sort_plan,
 )
-from repro.core.autotune import default_cache_path
 
 
 _keys = rand_keys
@@ -81,27 +73,6 @@ def run(sizes=(1 << 12, 1 << 15), p: int = 32,
             f"(static default w={DEFAULT_MAX_BINS_LOG2}/onehot)"
         row(f"sortplan/best/n{n}", t, f"w={w}/{engine} {marker}")
     return best
-
-
-# The shape points `tune` measures and persists: the BENCH_sort.json sort
-# points, the wide acceptance point, and the query layer's codec-driven
-# key widths (9-bit dictionary ids; 16-bit and full-word columns).
-TUNE_POINTS = (
-    (1 << 12, 16), (1 << 15, 32), (1 << 17, 32),
-    (1 << 15, 9), (1 << 15, 16),
-)
-
-
-def tune(points=TUNE_POINTS, force: bool = True):
-    """Measure the engine x width grid once per point and persist the
-    winners (the cache every entry point resolves through)."""
-    print(f"autotune cache: {default_cache_path()}")
-    for n, p in points:
-        plan = autotune_plan(n, p, force=force)
-        engines = sorted({dp.engine or "auto" for dp in plan.passes})
-        row(f"sortplan/tuned/n{n}/p{p}", 0.0,
-            f"plan={plan.describe()} engine={'+'.join(engines)}")
-    return None
 
 
 def run_rank_compare(sizes=(1 << 12, 1 << 15), p: int = 32,
@@ -233,7 +204,5 @@ if __name__ == "__main__":
         run_rank_compare()
     elif mode == "smoke":
         smoke(trace_out=_trace_out)
-    elif mode == "tune":
-        tune()
     else:
         run()

@@ -29,9 +29,7 @@ def row(name: str, seconds: float, derived: str = ""):
 
 def rand_keys(rng, n: int, p: int):
     """Uniform p-bit benchmark keys in the sort entry points' dtype
-    convention (uint32 for p=32, int32 below — mirrors
-    `repro.core.autotune._measure_plan` so tuner measurements and
-    benchmark points see the same distribution)."""
+    convention (uint32 for p=32, int32 below)."""
     import jax.numpy as jnp
 
     return jnp.asarray(

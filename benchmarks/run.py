@@ -27,7 +27,7 @@ import sys
 
 # The points every PR's BENCH_sort.json records: (n, p, max_bins_log2,
 # engine, smoke_guard).  max_bins_log2/engine None = the entry point's
-# default resolution (tuned plan when the host cache has one).  The
+# default plan.  The
 # per-engine points pin their plan exactly — they are the engine
 # trajectory across PRs, and the ``smoke_guard`` ones double as the CI
 # relative-regression baselines (bench_sortplan smoke re-times them and
@@ -167,7 +167,6 @@ def emit_sort_json(path: str = "BENCH_sort.json",
     from benchmarks.bench_query import query_points
     from benchmarks.common import rand_keys, time_fn
     from repro.core import fractal_sort, fractal_sort_stats, make_sort_plan
-    from repro.core.autotune import tuned_plan
 
     from repro import obs
 
@@ -182,7 +181,7 @@ def emit_sort_json(path: str = "BENCH_sort.json",
     for n, p, w, engine, guard in SORT_JSON_POINTS:
         keys = rand_keys(rng, n, p)
         if w is None:
-            plan = tuned_plan(n, p)  # the entry points' default resolution
+            plan = make_sort_plan(n, p)  # the entry points' default plan
         else:
             plan = make_sort_plan(n, p, max_bins_log2=w, engine=engine)
         with obs.suspended():  # time the sort, never the tracer

@@ -349,8 +349,6 @@ def main(argv=None) -> int:
         raise SystemExit(f"FAIL: {args.chips} chips asked, "
                          f"{len(devices)} present")
     print(f"compile cache: {use_compile_cache()}")
-    # plans come from the repo alone: no per-host autotune cache is read
-    os.environ["REPRO_AUTOTUNE_CACHE"] = os.devnull
     t0 = time.perf_counter()
     if args.chips == 4:
         phase_distributed(args.seed, FULL, 4)

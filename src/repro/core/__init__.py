@@ -17,11 +17,10 @@ from repro.core.fractal_tree import (
 from repro.core.sort_plan import (
     DEFAULT_MAX_BINS_LOG2,
     DigitPass,
+    ONEHOT_MAX_BITS,
     SortPlan,
+    default_engine,
     make_sort_plan,
-    pass_cost,
-    pick_engine,
-    plan_cost,
     rank_chunk_len,
     scatter_tile_len,
 )
@@ -45,10 +44,6 @@ from repro.core.fractal_sort import (
     fractal_sort_stats,
     rank_engine,
     reconstruct,
-)
-from repro.core.autotune import (
-    autotune_plan,
-    tuned_plan,
 )
 from repro.core.baselines import (
     bitonic_sort,

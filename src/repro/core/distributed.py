@@ -48,7 +48,8 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.executor import DistributedBackend, PlanExecutor
 from repro.core.fractal_sort import fractal_rank, rank_engine
-from repro.core.sort_plan import make_sort_plan, pick_engine, scatter_tile_len
+from repro.core.sort_plan import (default_engine, make_sort_plan,
+                                   scatter_tile_len)
 
 __all__ = [
     "distributed_fractal_sort",
@@ -77,8 +78,8 @@ def _distributed_pass(u: jnp.ndarray, shift: int, bits: int, axis: str,
     overflow flag.  ``engine`` picks the *local* rank engine for the
     pass's field (the wide-pass ICI scheme — ``max_bins_log2=16``, one
     all_to_all per 16-bit field — needs the scatter engine locally or the
-    2**16-bin one-hot tile dominates the collective); ``None`` defers to
-    the cost model.
+    2**16-bin one-hot tile dominates the collective); ``None`` picks by
+    digit width (:func:`~repro.core.sort_plan.default_engine`).
     """
     n_local = u.shape[0]
     D = jax.lax.psum(1, axis)
@@ -100,10 +101,10 @@ def _distributed_pass(u: jnp.ndarray, shift: int, bits: int, axis: str,
     global_start = jnp.concatenate(
         [jnp.zeros((1,), jnp.int32), jnp.cumsum(global_counts)[:-1]])
 
-    # local stable intra-bin arrival ranks (engine per the pass hint /
-    # cost model — wide fields rank via the scatter engine).
+    # local stable intra-bin arrival ranks (engine per the pass hint or
+    # the digit width — wide fields rank via the scatter engine).
     if engine is None:
-        engine = pick_engine(n_local, bits)
+        engine = default_engine(bits)
     rank_batch = scatter_tile_len(n_bins, batch) if engine == "scatter" \
         else batch
     rank_local, _, _ = rank_engine(engine)(field, n_bins, batch=rank_batch)

@@ -157,7 +157,8 @@ class PassBackend:
         Returns ``(rank, counts, carry_out)`` — the streaming-carry
         contract of :func:`~repro.core.fractal_sort.fractal_rank`.
         ``engine`` is the pass's rank-engine hint ("onehot"/"scatter");
-        ``None`` lets the backend pick (cost model or its native tile).
+        ``None`` lets the backend pick (by digit width or its native
+        tile).
         """
         raise NotImplementedError
 
@@ -221,38 +222,29 @@ class JnpBackend(PassBackend):
     scatter rank, jnp scatter).
 
     Engine selection: an explicit per-pass hint (``DigitPass.engine``)
-    wins; without one the analytic cost model
-    (:func:`~repro.core.sort_plan.pick_engine`) picks — narrow digits run
-    the one-hot tile, wide digits the scatter engine.  ``rank_fn`` pins
-    one rank function outright (benchmarks comparing engines on identical
-    plans); it overrides both the hint and the model.
+    wins; without one the digit width picks
+    (:func:`~repro.core.sort_plan.default_engine`) — narrow digits run
+    the one-hot tile, wide digits the scatter engine.
     """
 
-    def __init__(self, batch: int = 1024, rank_fn=None):
+    def __init__(self, batch: int = 1024):
         self.batch = batch
         self.rank_base = batch  # the user batch knob feeds the pass hints
-        self.rank_fn = rank_fn
 
     def rank(self, digit, n_bins, *, batch_hint=None, carry_in=None,
              bin_start=None, engine=None):
         from repro.core.fractal_sort import rank_engine
-        from repro.core.sort_plan import pick_engine, scatter_tile_len
+        from repro.core.sort_plan import default_engine, scatter_tile_len
 
-        if self.rank_fn is not None:
-            fn = self.rank_fn
-            batch = self.batch if batch_hint is None else batch_hint
-        else:
-            if engine is None:
-                bits = max(n_bins - 1, 1).bit_length()
-                engine = pick_engine(digit.shape[0], bits)
-                # a hint computed for the other engine's tile shape must
-                # not leak in: re-derive it for the picked engine.
-                if engine == "scatter":
-                    batch_hint = scatter_tile_len(n_bins, self.batch)
-            fn = rank_engine(engine)
-            batch = self.batch if batch_hint is None else batch_hint
-        return fn(digit, n_bins, batch=batch, carry_in=carry_in,
-                  bin_start=bin_start)
+        if engine is None:
+            engine = default_engine(max(n_bins - 1, 1).bit_length())
+            # a hint computed for the other engine's tile shape must not
+            # leak in: re-derive it for the picked engine.
+            if engine == "scatter":
+                batch_hint = scatter_tile_len(n_bins, self.batch)
+        batch = self.batch if batch_hint is None else batch_hint
+        return rank_engine(engine)(digit, n_bins, batch=batch,
+                                   carry_in=carry_in, bin_start=bin_start)
 
     def reconstruct(self, counts, trailing, plan):
         from repro.core.fractal_sort import reconstruct

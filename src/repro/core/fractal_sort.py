@@ -525,18 +525,11 @@ def _resolve_plan(n: int, p: int, l_n: Optional[int],
                   max_bins_log2: Optional[int],
                   plan: Optional[SortPlan]) -> SortPlan:
     """Plan resolution shared by every entry point: an explicit ``plan``
-    wins; explicit ``l_n``/``max_bins_log2`` build the classical static
-    plan; all-defaults consults the per-host autotune cache
-    (:func:`~repro.core.autotune.tuned_plan` — free, never measures, and
-    identical to the static default until a sweep has recorded a
-    winner)."""
+    wins; otherwise :func:`~repro.core.sort_plan.make_sort_plan` builds
+    it from ``l_n`` and ``max_bins_log2`` (each defaulting there)."""
     if plan is not None:
         assert plan.p == p, f"plan is for p={plan.p}, sort asked p={p}"
         return plan
-    if l_n is None and max_bins_log2 is None:
-        from repro.core.autotune import tuned_plan
-
-        return tuned_plan(n, p)
     return make_sort_plan(n, p, l_n=l_n, max_bins_log2=max_bins_log2)
 
 
@@ -550,10 +543,8 @@ def fractal_sort(keys: jnp.ndarray, p: int, l_n: Optional[int] = None,
     """Sort integer keys in [0, 2**p) by executing a :class:`SortPlan`:
     bounded-width stable LSD digit passes plus one fractal MSD pass
     ("compressed entries").  ``max_bins_log2`` caps per-pass bins at
-    ``2**max_bins_log2``; ``plan`` pins an exact plan (e.g. from
-    :func:`~repro.core.autotune.autotune_plan`); all-defaults runs the
-    host's tuned plan when one is cached, else the static
-    ``DEFAULT_MAX_BINS_LOG2`` plan."""
+    ``2**max_bins_log2``; ``plan`` pins an exact plan; all-defaults runs
+    the ``DEFAULT_MAX_BINS_LOG2`` plan."""
     n = keys.shape[0]
     plan = _resolve_plan(n, p, l_n, max_bins_log2, plan)
     return PlanExecutor(JnpBackend(batch=batch)).run(keys, plan)

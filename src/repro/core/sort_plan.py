@@ -28,16 +28,10 @@ inputs (n=64, p=16) get a few 5-bit passes instead of one 1024-bin pass.
 is the *one-hot* engine's; the *scatter* engine
 (:func:`~repro.core.fractal_sort.fractal_rank_scatter`) ranks a pass in
 O(n log tile) independent of the digit width, which is what makes wide
-passes executable on CPU at all.  Each :class:`DigitPass` carries an
-optional ``engine`` hint ("onehot" / "scatter" / ``None`` = let the
-backend pick via the analytic cost model below); hints are *execution*
-metadata — two plans differing only in hints sort identically.
-:func:`pass_cost` / :func:`plan_cost` model the trade analytically (in
-"bin-column units": one elementwise op over an n-row one-hot column), and
-:func:`pick_engine` is the model's per-pass argmin.  The real winner per
-host is measured once by :func:`~repro.core.autotune.autotune_plan` and
-cached; the model seeds the candidate grid and serves as the no-cache
-default.
+passes executable at all.  Each :class:`DigitPass` carries an optional
+``engine`` hint ("onehot" / "scatter" / ``None`` = by digit width, as
+:func:`default_engine` says); hints are *execution* metadata — two plans
+differing only in hints sort identically.
 """
 
 from __future__ import annotations
@@ -51,11 +45,10 @@ from repro.core import fractal_tree as ft
 __all__ = [
     "DEFAULT_MAX_BINS_LOG2",
     "DigitPass",
+    "ONEHOT_MAX_BITS",
     "SortPlan",
+    "default_engine",
     "make_sort_plan",
-    "pass_cost",
-    "pick_engine",
-    "plan_cost",
     "quantize_sort_bits",
     "rank_chunk_len",
     "scatter_tile_len",
@@ -134,52 +127,16 @@ def scatter_tile_len(n_bins: int, base: int = 1024) -> int:
     return max(min(max(tile, _SCATTER_TILE_MIN), _SCATTER_TILE_MAX), base)
 
 
-# --- analytic per-pass cost model (engine selection prior) ------------------
-#
-# Unit: one elementwise op over an n-row one-hot bin column ("bin-column
-# unit"), the natural cost unit of the one-hot engine.  Calibrated on this
-# host at n = 2**17 (see BENCH_sort.json / bench_sortplan's engines mode):
-# the one-hot rank costs ~n * n_bins units; the scatter engine's tile sort
-# plus gathers cost the equivalent of ~32 bin columns regardless of width,
-# plus a per-(tiles x n_bins) histogram-table term that only matters for
-# very wide digits.  The model exists to pick sane defaults *without* a
-# measurement cache — `autotune_plan` measures the real crossover per host
-# and overrides it.
-_SCATTER_BASE_UNITS = 32
-_SCATTER_TABLE_UNITS = 8
+#: Widest digit a pass without an engine hint ranks on the one-hot engine.
+#: This is where the analytic cost model the engines were once picked by
+#: crossed over, at every n: one-hot work grows as 2**bits a key, the
+#: scatter engine's stays ~32 bin columns a key.
+ONEHOT_MAX_BITS = 5
 
 
-def pass_cost(n: int, bits: int, engine: str) -> float:
-    """Analytic rank cost of one ``bits``-wide pass over ``n`` keys, in
-    bin-column units (relative — compare across (bits, engine), not
-    hosts)."""
-    n_bins = 1 << bits
-    if engine == "onehot":
-        return float(n) * n_bins
-    assert engine == "scatter", f"unknown engine {engine!r}"
-    tile = scatter_tile_len(n_bins)
-    return float(n) * (_SCATTER_BASE_UNITS
-                       + _SCATTER_TABLE_UNITS * n_bins / tile)
-
-
-def pick_engine(n: int, bits: int) -> str:
-    """The cost model's per-pass engine argmin (the no-cache default the
-    JnpBackend applies when a pass carries no explicit hint)."""
-    return min(("onehot", "scatter"), key=lambda e: pass_cost(n, bits, e))
-
-
-def plan_cost(plan: "SortPlan", engine: Optional[str] = None) -> float:
-    """Analytic rank cost of a whole plan (bin-column units): the sum of
-    per-pass costs under each pass's engine hint, ``engine`` overriding
-    unhinted passes (``None`` = the cost model's own pick).  Key *traffic*
-    is deliberately excluded — it is O(n * passes) for every engine and
-    already modeled by ``fractal_sort_stats``; this function ranks rank-
-    stage arithmetic, the term that used to force narrow plans."""
-    total = 0.0
-    for dp in plan.passes:
-        e = dp.engine or engine or pick_engine(plan.n, dp.bits)
-        total += pass_cost(plan.n, dp.bits, e)
-    return total
+def default_engine(bits: int) -> str:
+    """The rank engine of a ``bits``-wide pass that carries no hint."""
+    return "onehot" if bits <= ONEHOT_MAX_BITS else "scatter"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -188,7 +145,7 @@ class DigitPass:
 
     ``engine`` is an execution hint — "onehot" (materialized one-hot tile,
     MXU-shaped), "scatter" (sorted-tile scatter/bincount engine), or
-    ``None`` (backend picks via :func:`pick_engine`).  Hints never change
+    ``None`` (by digit width: :func:`default_engine`).  Hints never change
     the sorted output, only how ranks are computed."""
 
     shift: int
@@ -271,7 +228,7 @@ def make_sort_plan(n: int, p: int, l_n: Optional[int] = None,
     ``n_bins`` never dwarfs ``n``.
 
     ``engine`` stamps every pass's rank-engine hint ("onehot"/"scatter";
-    ``None`` leaves the choice to the executing backend's cost model).
+    ``None`` leaves the choice to the digit width: :func:`default_engine`).
 
     Degenerate widths are legal and *skipped*, never executed: ``p = 0``
     (every key is the zero-width value — the external sort reaches this

@@ -203,7 +203,7 @@ def fractal_rank_counts(digit: jnp.ndarray, n_bins: int,
     may be supplied when the global histogram is already known
     (distributed merge).  ``engine`` is the plan's per-pass hint; ``None``
     keeps the one-hot kernel — the MXU-shaped tile is the TPU-native
-    default, so the kernel driver does *not* apply the CPU cost model.
+    default, so the kernel driver does *not* pick by digit width.
     ``"scatter"`` runs in interpret mode only and raises compiled.
     """
     from repro.core.fractal_tree import exclusive_cumsum

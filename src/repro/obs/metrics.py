@@ -1,8 +1,8 @@
 """One process-wide metrics registry for every counter in the repo.
 
-The repo grew four disjoint counter islands — ``core/dispatch.py`` jit-site
-counts, ``RunStore.events`` + put/get logs, ``core/faults.py`` retry loops,
-autotune consult counts — each with its own ad-hoc snapshot idiom.  This
+The repo grew disjoint counter islands — ``core/dispatch.py`` jit-site
+counts, ``RunStore.events`` + put/get logs, ``core/faults.py`` retry
+loops — each with its own ad-hoc snapshot idiom.  This
 module is the single sink they all feed: named :class:`Counter` /
 :class:`Gauge` / :class:`Histogram` instruments plus structured
 :func:`event` records, created on first touch and process-wide for the
@@ -14,7 +14,7 @@ answer p50/p99 — the serving-layer latency primitive the ROADMAP's
 sort-as-a-service item builds on, via :func:`track`.
 
 Deliberately dependency-free (stdlib only, no ``repro.*`` imports):
-``dispatch``, ``faults``, ``chunks`` and ``autotune`` all import this
+``dispatch``, ``faults`` and ``chunks`` all import this
 module, so it must sit below every other layer.
 """
 from __future__ import annotations

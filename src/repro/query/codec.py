@@ -107,18 +107,15 @@ class Codec:
     def num_words(self) -> int:
         return len(word_widths(self.bits))
 
-    def word_plans(self, n: int, backend: str = "jnp") -> tuple:
-        """Per-word tuned sort plans for an ``n``-row column: one plan per
-        emitted uint32 word, each sized to that word's exact bit width and
-        resolved through the host's autotune cache
-        (:func:`~repro.core.autotune.tuned_plan` — free, never measures).
-        This is how codec-driven key widths (9-bit ids, 41-bit composites)
-        pick up wide scatter-engine passes instead of the global static
-        default."""
-        from repro.core.autotune import tuned_plan
+    def word_plans(self, n: int) -> tuple:
+        """Per-word sort plans for an ``n``-row column: one default plan
+        per emitted uint32 word, each sized to that word's exact bit
+        width (:func:`~repro.core.sort_plan.make_sort_plan`), so
+        codec-driven key widths (9-bit ids, 41-bit composites) reach the
+        planner instead of a global 32-bit default."""
+        from repro.core.sort_plan import make_sort_plan
 
-        return tuple(tuned_plan(n, w, backend=backend)
-                     for w in word_widths(self.bits))
+        return tuple(make_sort_plan(n, w) for w in word_widths(self.bits))
 
     def prepare(self, col):
         """Host boundary: the column as trace-ready arrays (bitcast /

@@ -45,7 +45,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import JnpBackend, PlanExecutor, SortPlan, dispatch
+from repro.core import (JnpBackend, PlanExecutor, SortPlan, dispatch,
+                        make_sort_plan)
 from repro.obs import metrics, trace
 from repro.query.codec import (
     Codec,
@@ -163,12 +164,10 @@ def active_words(bits: int, low_bits: Optional[int] = None,
 
 
 def _resolve_plans(n: int, active, plans):
-    """Per-active-word plans: caller-pinned, or one autotune-cache consult
-    per active word (:func:`~repro.core.autotune.tuned_plan`)."""
+    """Per-active-word plans: caller-pinned, or the default plan of each
+    active word's width (:func:`~repro.core.sort_plan.make_sort_plan`)."""
     if plans is None:
-        from repro.core.autotune import tuned_plan
-
-        plans = tuple(tuned_plan(n, eff) for _, eff in active)
+        plans = tuple(make_sort_plan(n, eff) for _, eff in active)
     assert len(plans) == len(active), (
         f"{len(active)} active words need {len(active)} plans, "
         f"got {len(plans)}")
@@ -287,10 +286,8 @@ def sort_rowids(words: jnp.ndarray, bits: int,
     arrival order — already the stable sorted order.
 
     ``plans`` pins per-word :class:`SortPlan`\\ s (one per *active* word
-    of the code); by default each active word resolves through the
-    per-host autotune cache (:func:`~repro.core.autotune.tuned_plan`), so
-    codec-driven key widths get wide scatter-engine passes wherever the
-    host's sweep found them faster.
+    of the code); by default each active word gets the default plan of
+    its own width (:func:`~repro.core.sort_plan.make_sort_plan`).
     """
     widths = word_widths(bits)
     n = words.shape[0]
@@ -431,7 +428,7 @@ def order_by(table: Table, by, codecs: Optional[Mapping[str, Codec]] = None,
              placement=None) -> Table:
     """Multi-column ORDER BY (stable): rows reordered by one gather of the
     pairs sort's row-id payload.  ``plans`` pins per-word sort plans
-    (default: the host's tuned plans for the codec's word widths).
+    (default: the default plans for the codec's word widths).
 
     A StreamTable input runs out-of-core and returns a StreamTable of
     sorted runs (:func:`~repro.stream.table_ops.stream_order_by`);
@@ -444,7 +441,7 @@ def order_by(table: Table, by, codecs: Optional[Mapping[str, Codec]] = None,
     if stream is not None:
         assert plans is None, (
             "pinned plans don't apply out-of-core: each partition "
-            "resolves tuned plans for its own length")
+            "resolves plans for its own length")
         return stream.stream_order_by(table, by, codecs,
                                       placement=placement)
     assert placement is None, (
@@ -496,7 +493,7 @@ def top_k(table: Table, by, k: int,
     candidate sort is the global stable sort restricted to a prefix-closed
     key range.  ``plans`` applies when the sort runs over all ``n`` rows
     (k >= n, or no pruning opportunity); a pruned candidate subset
-    re-resolves tuned plans for its own (smaller) length.  The histogram
+    re-resolves plans for its own (smaller) length.  The histogram
     and the candidate pick are the span ``query.prune``, the final
     gather ``query.take``.
     """
@@ -504,7 +501,7 @@ def top_k(table: Table, by, k: int,
     if stream is not None:
         assert plans is None, (
             "pinned plans don't apply out-of-core: each partition "
-            "resolves tuned plans for its own length")
+            "resolves plans for its own length")
         return stream.stream_top_k(table, by, k, codecs, store=placement)
     assert placement is None, (
         "placement is the out-of-core fragment store; an in-memory Table "
@@ -533,7 +530,7 @@ def _top_k_mem(table: Table, by, k: int, codecs, plans) -> Table:
             if size < n:
                 sub_pre = jax.tree_util.tree_map(lambda a: a[rows], prepped)
     if sub_pre is not None:
-        # the candidate subset re-resolves its own (tuned) plans:
+        # the candidate subset re-resolves its own plans:
         # caller-pinned plans were sized for n rows, not ~k
         _, sub = sort_rowids_fused(codec, sub_pre)
         picked = rows[sub[:k]]
@@ -625,7 +622,7 @@ def group_by(table: Table, by, aggs: Mapping[str, Tuple[Optional[str], str]],
     if stream is not None:
         assert plans is None, (
             "pinned plans don't apply out-of-core: each partition "
-            "resolves tuned plans for its own length")
+            "resolves plans for its own length")
         return stream.stream_group_by(table, by, aggs, codecs,
                                       placement=placement)
     assert placement is None, (
@@ -691,7 +688,7 @@ def sort_merge_join(left: Table, right: Table, on,
     cross-word-boundary ties behave exactly as one wide integer key.
     ``plans`` (one per code word) applies to *both* sides' sorts; leave
     it None when the two tables differ widely in size so each side
-    resolves its own tuned plan.
+    resolves its own plan.
 
     Its host phases are the spans ``query.merge`` (the two probes),
     ``query.expand`` (the repeat and position arrays) and ``query.take``

@@ -79,7 +79,7 @@ import numpy as np
 from repro.core.executor import PlanExecutor
 from repro.core.faults import StoreError, StorePermanentError
 from repro.core.fractal_tree import ceil_log2
-from repro.core.sort_plan import DigitPass, quantize_sort_bits
+from repro.core.sort_plan import DigitPass, make_sort_plan, quantize_sort_bits
 from repro.obs import metrics, trace
 from repro.query.codec import word_widths
 from repro.stream.chunks import (
@@ -368,21 +368,14 @@ def stream_sorted_words(
                 [int(r) for r in ids] for ids in frag_ids]
             store.write_log(journal, manifest)
 
-    # per-call plan hoisting: tuned plans resolve ONCE per (padded
-    # length, sort-bits) bucket, not once per partition — the autotune
-    # cache is consulted O(buckets) times per external-sort call.
-    plan_cache: dict = {}
-
     def plans_for(padded_len, sort_bits):
-        key = (padded_len, sort_bits)
-        if key not in plan_cache:
-            from repro.core.autotune import tuned_plan
-            from repro.query.operators import active_words
+        """One default plan per active word, sized for the bucket's
+        padded length: every partition of a (length, sort-bits) bucket
+        gets equal plans, so they share one compiled chain."""
+        from repro.query.operators import active_words
 
-            plan_cache[key] = tuple(
-                tuned_plan(padded_len, eff)
-                for _, eff in active_words(bits, sort_bits))
-        return plan_cache[key]
+        return tuple(make_sort_plan(padded_len, eff)
+                     for _, eff in active_words(bits, sort_bits))
 
     def part_bucket(part):
         """(padded pow2 length, quantized sort bits) — the shared-trace

@@ -174,7 +174,7 @@ def test_jnp_and_pallas_backends_agree_mixed_engine_hints(rng):
 
 @pytest.mark.parametrize("engine", ["onehot", "scatter"])
 def test_engine_hinted_plans_sort_correctly(rng, engine):
-    """Whole-plan engine stamps (what `autotune_plan` records) across
+    """Whole-plan engine stamps (``make_sort_plan(..., engine=)``) across
     widths, including the paper's 16-bit field under the scatter engine —
     the plan the one-hot engine could never execute in reasonable time."""
     for n, p, w in [(3000, 16, 8), (2048, 32, 11), (2048, 32, 16)]:

@@ -18,10 +18,12 @@ from repro.core import (
     fractal_argsort,
     fractal_sort,
     fractal_sort_batched,
+    fractal_sort_pairs,
     fractal_sort_stats,
     get_index,
     get_item,
     histogram_nbytes,
+    make_sort_plan,
     merge_histograms,
     reconstruct,
     taper_levels,
@@ -168,3 +170,25 @@ def test_sort_stats_bandwidth_model():
     # radix comparison: fractal must move fewer bytes than 4-pass radix
     from repro.core import radix_sort_stats
     assert st32.bytes_total < radix_sort_stats(1 << 20, 32).bytes_total
+
+
+def test_entry_points_accept_pinned_plans(rng):
+    """plan= must reach every entry point unchanged (zero API breakage:
+    the old signatures still work, the new static arg pins execution)."""
+    n, p = 2048, 16
+    keys = rng.integers(0, 1 << p, n).astype(np.int32)
+    arr = jnp.asarray(keys)
+    plan = make_sort_plan(n, p, max_bins_log2=8, engine="scatter")
+    np.testing.assert_array_equal(
+        np.asarray(fractal_sort(arr, p, plan=plan)), np.sort(keys))
+    perm = fractal_argsort(arr, p, plan=plan)
+    np.testing.assert_array_equal(np.asarray(perm),
+                                  np.argsort(keys, kind="stable"))
+    vals = jnp.arange(n, dtype=jnp.int32)
+    sk, sv = fractal_sort_pairs(arr, vals, p, plan=plan)
+    np.testing.assert_array_equal(np.asarray(sv),
+                                  np.argsort(keys, kind="stable"))
+    streamed, _ = fractal_sort_batched(arr, p, 4, plan=plan)
+    np.testing.assert_array_equal(np.asarray(streamed), np.sort(keys))
+    with pytest.raises(AssertionError):
+        fractal_sort(arr, 12, plan=plan)  # plan/p mismatch is loud

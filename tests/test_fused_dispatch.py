@@ -1,5 +1,5 @@
 """Fused encode→sort dispatch: parity, dispatch counts, batched stream
-sorts, and the autotune-consult budget.
+sorts, and the external sort's compile budget.
 
 The tentpole invariants this file pins:
 
@@ -14,8 +14,8 @@ The tentpole invariants this file pins:
 * the stream path's batched partition sorts are bit-identical to the
   serial per-partition path under a tight budget, and actually engage on
   skewed data;
-* an external-sort call consults the autotune cache O(plan buckets)
-  times, not O(partitions).
+* an external-sort call compiles one sort chain per (length, sort-bits)
+  bucket, not one per partition.
 """
 
 from __future__ import annotations
@@ -26,12 +26,13 @@ import pytest
 import jax.numpy as jnp
 
 from repro.core import JnpBackend, PallasBackend, PlanExecutor, dispatch
-from repro.core.autotune import consult_count
 from repro.core.sort_plan import make_sort_plan
 from repro.query import Table, order_by
 from repro.query.operators import (
     _key_data,
     _normalize_by,
+    _rowid_chain,
+    _segmented_chain,
     sort_rowids,
     sort_rowids_fused,
 )
@@ -199,24 +200,29 @@ def test_stream_batched_equals_serial_partition_sorts(tmp_path):
             < serial_seen.get("query.chain", 0))
 
 
-def test_autotune_consults_per_bucket_not_per_partition():
-    """One external-sort call resolves tuned plans O(distinct (length,
-    sort-bits) buckets) times; with 8 budget-packed uniform partitions
-    sharing one bucket that is a handful of consults, never one per
-    partition (and never one per chunk)."""
+def test_external_sort_compiles_one_chain_per_bucket():
+    """One external-sort call compiles one sort chain per distinct
+    (padded length, sort-bits) bucket, counted at the chain's jit site;
+    8 budget-packed uniform partitions share a handful of buckets, so
+    they compile a handful of chains, never one per partition."""
+    _rowid_chain.cache_clear()  # fresh jit caches: every compile counts
+    _segmented_chain.cache_clear()
     rng = np.random.default_rng(9)
     n = 1 << 14
     keys = rng.integers(0, 1 << 32, n, dtype=np.uint64).astype(np.uint32)
     budget = MemoryBudget(n * 4 // 8)
     src = ArraySource(keys, budget.rows(row_cost_bytes(1)))
-    before = consult_count()
-    chunks = list(external_sort(src, 32, budget))
-    consults = consult_count() - before
+    with dispatch.track() as seen:
+        chunks = list(external_sort(src, 32, budget))
+    tags = ("query.chain", "query.segmented_chain")
+    runs = sum(seen.get(t, 0) for t in tags)
+    compiles = sum(seen.get(f"{t}:compiles", 0) for t in tags)
     assert np.array_equal(np.concatenate(chunks), np.sort(keys))
     assert len(chunks) >= 8, "expected ≥8 partitions for this ratio"
-    assert 0 < consults <= 4, (
-        f"{consults} autotune consults for {len(chunks)} partitions: "
-        "plan resolution regressed to per-partition lookups")
+    assert runs >= 2, f"{runs} chain runs for {len(chunks)} partitions"
+    assert 0 < compiles <= 4, (
+        f"{compiles} chain compiles for {len(chunks)} partitions: "
+        "plans regressed to one program per partition")
 
 
 # ---------------------------------------------------------------------------

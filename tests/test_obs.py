@@ -376,19 +376,6 @@ def test_bandwidth_report_measured_vs_analytic():
 # --- layer counters ----------------------------------------------------------
 
 
-def test_autotune_hit_miss_counters(tmp_path):
-    from repro.core.autotune import autotune_plan
-
-    cache = str(tmp_path / "tune.json")
-    before = metrics.snapshot()
-    autotune_plan(1 << 12, 16, cache_path=cache, measure=False)  # miss
-    autotune_plan(1 << 12, 16, cache_path=cache, measure=False)  # miss
-    after = metrics.snapshot()
-    assert after.get("autotune.consults", 0) - \
-        before.get("autotune.consults", 0) == 2
-    assert after.get("autotune.miss", 0) - before.get("autotune.miss", 0) == 2
-
-
 def test_memory_budget_peak_gauge():
     budget = MemoryBudget(1 << 20)
     with budget.hold(np.zeros(1 << 14, dtype=np.int32)):

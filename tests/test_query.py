@@ -539,7 +539,7 @@ def test_rowid_chain_is_cached_across_calls(rng):
 
 
 def test_sort_rowids_accepts_pinned_plans(rng):
-    """Explicit per-word plans (the autotune output) must flow through the
+    """Explicit per-word plans (caller-pinned) must flow through the
     chain and sort identically to the defaults."""
     from repro.core import make_sort_plan
 

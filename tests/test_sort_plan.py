@@ -3,6 +3,7 @@ precisions and adversarial distributions, argsort stability, batched
 merge telescoping, and per-pass traffic accounting."""
 
 import functools
+import importlib
 
 import numpy as np
 import jax.numpy as jnp
@@ -10,14 +11,21 @@ import pytest
 
 from repro.core import (
     DEFAULT_MAX_BINS_LOG2,
+    ONEHOT_MAX_BITS,
+    JnpBackend,
     build_histogram,
+    default_engine,
     fractal_argsort,
     fractal_sort,
     fractal_sort_batched,
+    fractal_rank_serial,
     fractal_sort_stats,
     make_sort_plan,
     merge_histograms,
+    scatter_tile_len,
 )
+from repro.core.fractal_sort import _resolve_plan
+from repro.query.operators import _resolve_plans, active_words
 
 
 # --- plan construction -------------------------------------------------------
@@ -59,17 +67,15 @@ def test_plan_tiny_inputs_bound_bins_by_data_scale():
 
 def test_plan_p0_identity_and_no_degenerate_passes():
     """p=0 (zero-width keys — the external sort's exhausted-recursion
-    case) is the empty identity plan, resolved without touching the
-    autotune cache; no plan ever emits a 1-bin (zero-width) pass."""
-    from repro.core import (PlanExecutor, JnpBackend, fractal_argsort,
-                            tuned_plan)
+    case) is the empty identity plan; no plan ever emits a 1-bin
+    (zero-width) pass."""
+    from repro.core import PlanExecutor, JnpBackend, fractal_argsort
 
     plan = make_sort_plan(100, 0)
     assert plan.passes == ()
     assert plan.depth == 0 and plan.trailing_bits == 0
     assert not plan.supports_grouped_trailing
     assert plan.describe() == "identity"
-    assert tuned_plan(1 << 20, 0).passes == ()
     keys = jnp.zeros((17,), jnp.int32)
     ex = PlanExecutor(JnpBackend())
     assert np.array_equal(np.asarray(ex.run(keys, plan)), np.zeros(17))
@@ -80,6 +86,77 @@ def test_plan_p0_identity_and_no_degenerate_passes():
     for n in (1, 64, 5000):
         for p in range(1, 33):
             assert all(dp.bits >= 1 for dp in make_sort_plan(n, p).passes)
+
+
+@pytest.mark.parametrize("bits", range(1, 17))
+def test_default_engine_by_width(bits, monkeypatch):
+    """A pass with no engine hint ranks one-hot at 1-5 bits and through
+    the scatter engine at 6-16, the table the analytic cost model gave at
+    every n.  On each side of the threshold the picked engine ranks
+    exactly as the serial-scan oracle does."""
+    want = "onehot" if bits <= 5 else "scatter"
+    assert ONEHOT_MAX_BITS == 5
+    assert default_engine(bits) == want
+    # scatter tiles grow with the digit (the one-hot chunk hint shrinks)
+    assert scatter_tile_len(1 << bits) >= scatter_tile_len(1 << 4)
+    if bits not in (ONEHOT_MAX_BITS, ONEHOT_MAX_BITS + 1):
+        return
+    fs = importlib.import_module("repro.core.fractal_sort")
+    picked = []
+
+    def spy(engine):
+        def ranked(*args, batch, **kw):
+            picked.append((engine, batch))
+            return fs.RANK_ENGINES[engine](*args, batch=batch, **kw)
+        return ranked
+
+    monkeypatch.setattr(fs, "rank_engine", spy)
+    n, n_bins = 700, 1 << bits
+    digit = jnp.asarray(np.random.default_rng(bits).integers(0, n_bins, n),
+                        jnp.int32)
+    rank, counts, _ = JnpBackend().rank(digit, n_bins, engine=None)
+    want_rank, want_counts, _ = fractal_rank_serial(digit, n_bins)
+    # a scatter pass re-derives its tile from the digit width
+    tile = scatter_tile_len(n_bins, 1024) if want == "scatter" else 1024
+    assert picked == [(want, tile)]
+    np.testing.assert_array_equal(np.asarray(rank), np.asarray(want_rank))
+    np.testing.assert_array_equal(np.asarray(counts),
+                                  np.asarray(want_counts))
+
+
+# (n, key bits, default plan) of every sort the benchmark cells run: bulk
+# 2^27 keys at p=16; Q18's LINEITEM; Q3's LINEITEM and first-join output
+# on orderkey, its ORDERS and CUSTOMER on custkey; Q1's 3-bit GROUP BY
+# key over the rows its filter keeps; and the words of Q3's 41-bit GROUP
+# BY key and 78-bit top-k key as active_words splits them.
+_W32 = "+".join(["4b"] * 8)
+_CELL_SORTS = {
+    "bulk": (1 << 27, 16, ("4b+4b+4b+4b",)),
+    "q18.lineitem": (59_986_052, 26, ("4b+4b+4b+4b+3b+3b+4b",)),
+    "q3.lineitem": (32_336_621, 26, ("4b+4b+4b+4b+3b+3b+4b",)),
+    "q3.first_join": (1_460_000, 26, ("4b+4b+4b+4b+3b+3b+4b",)),
+    "q3.orders": (7_290_000, 21, ("4b+4b+3b+3b+3b+4b",)),
+    "q3.customer": (300_000, 21, ("4b+4b+3b+3b+3b+4b",)),
+    "q1.flags": (59_142_284, 3, ("3b",)),
+    "q3.group_by": (300_000, 41, (_W32, "3b+2b+4b")),
+    "q3.top_k": (114_000, 78, (_W32, _W32, "4b+3b+3b+4b")),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_SORTS))
+def test_cell_default_plans(cell):
+    """Every cell's sort resolves to make_sort_plan's default, through
+    the entry points and the query operators alike, and ranks each pass
+    one-hot.  Plan objects only: no arrays."""
+    n, bits, want = _CELL_SORTS[cell]
+    active = active_words(bits)
+    plans = _resolve_plans(n, active, None)
+    assert plans == tuple(make_sort_plan(n, eff) for _, eff in active)
+    assert tuple(p.describe() for p in plans) == want
+    for (_, eff), plan in zip(active, plans):
+        assert _resolve_plan(n, eff, None, None, None) == plan
+        assert all(dp.engine is None and default_engine(dp.bits) == "onehot"
+                   for dp in plan.passes)
 
 
 def test_plan_explicit_ln_wins_over_cap():
